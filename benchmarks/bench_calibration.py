@@ -25,11 +25,11 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
-import subprocess
 import sys
 import time
+
+import jax
 
 from repro.kernels import calibrate
 from repro.sim.report import row
@@ -45,7 +45,8 @@ TABLE_RT_TOL = 1e-12          # measured table reproduces its own samples
 def measure(full: bool):
     grid = "full" if full else "quick"
     t0 = time.perf_counter()
-    records, meta = calibrate.measure(grid=grid, repeat=3 if full else 2)
+    records, meta = calibrate.measure(grid=grid, repeat=3 if full else 2,
+                                      interpret=True)
     t_measure = time.perf_counter() - t0
     out = calibrate.build_report(records, meta)
     out["budget_s"] = {f"measure_{grid}_grid": round(t_measure, 6)}
@@ -130,18 +131,13 @@ def main():
     if failed:
         sys.exit(1)
     # record the quick-grid budget too, so --quick has one to gate on.
-    # A fresh subprocess, not this warm process: --quick pays kernel
-    # tracing inside its measured wall, and a warm-cache budget would
-    # gate every cold CI run as a false regression.
+    # --quick pays kernel tracing inside its measured wall, so this
+    # process drops its compiled kernels first: a warm-cache budget would
+    # gate every cold CI run as a false regression.  It stays in this
+    # process, since a child could not reach a chip this one holds.
+    jax.clear_caches()
     t0 = time.perf_counter()
-    subprocess.run(
-        [sys.executable, "-c",
-         "from repro.kernels import calibrate; "
-         "calibrate.measure(grid='quick', repeat=2)"],
-        check=True, cwd=ROOT,
-        env={**os.environ,
-             "PYTHONPATH": str(ROOT / "src") + os.pathsep
-             + os.environ.get("PYTHONPATH", "")})
+    calibrate.measure(grid="quick", repeat=2, interpret=True)
     out["budget_s"]["measure_quick_grid"] = round(
         time.perf_counter() - t0, 6)
     out["recorded"] = time.strftime("%Y-%m-%d")

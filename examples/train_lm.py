@@ -1,7 +1,7 @@
 """End-to-end training driver: data pipeline -> sharded train step ->
 async checkpointing -> restart/restore.  The same script scales from this
 CPU container (--preset cpu-small: ~5M params, a few hundred steps) to the
-production pod (--preset pod: full config + 16x16 mesh via launch/train.py).
+full config (--preset full); repro.launch.train runs it over the local chips.
 
   PYTHONPATH=src python examples/train_lm.py --steps 60 --preset cpu-small
 """

@@ -15,7 +15,13 @@ FULL = ModelConfig(
     activation="swiglu",
     tie_embeddings=True,
     rope_theta=10_000.0,
-    moe=MoEConfig(n_experts=32, top_k=8, n_shared=0, d_ff_expert=512),
+    # the published model is dropless: every token reaches its top-8
+    # experts.  A capacity factor of n_experts / top_k = 4 gives each
+    # expert a slot for every token, so none is dropped at any batch size
+    # (at 1.25, an 8-token decode step would drop tokens that a prefill
+    # of the same tokens keeps).
+    moe=MoEConfig(n_experts=32, top_k=8, n_shared=0, d_ff_expert=512,
+                  capacity_factor=4.0),
 )
 
 SMOKE = ModelConfig(

@@ -1,29 +1,10 @@
-"""JAX version compatibility shims.
-
-The repo targets the newest public API (``jax.shard_map``, dict-returning
-``cost_analysis``); the container may run an older jax.  All version probing
-lives here so the rest of the code imports one stable surface.
-"""
+"""``shard_map`` with JAX's replication checking off: the EP MoE path and
+the pipeline psum explicitly."""
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict (older jax returns a
-    one-element list of dicts, newer returns the dict)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

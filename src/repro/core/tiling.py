@@ -24,7 +24,12 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from repro.core.tensor import TensorSpec
 
 # hardware constants (TPU v5e)
-VMEM_BYTES = 128 * 1024 * 1024      # per-core vector memory
+# A v5e TensorCore has 128 MiB of VMEM, but Mosaic compiles each Pallas
+# kernel under a scoped limit of 16 MiB unless the call raises it:
+# compiling the f32 4096^3 matmul with 512x512x2048 blocks for a
+# described v5e fails with "Scoped allocation with size 19.00M and limit
+# 16.00M".  Kernel tiles are sized to that limit.
+VMEM_LIMIT = 16 * 1024 * 1024       # per-kernel scoped VMEM (v5e default)
 MXU_DIM = 128                       # systolic array is 128x128
 LANE = 128                          # last-dim register lane quantum
 SUBLANE = 8                         # second-minor quantum (fp32)
@@ -160,13 +165,20 @@ class MatmulTiling:
     util_k: float
 
 
+def matmul_vmem_bytes(bm: int, bn: int, bk: int, dtype_bytes: int) -> int:
+    """VMEM the matmul kernel allocates for one block shape: the pipeline
+    double-buffers the A, B and output blocks, plus the fp32 accumulator."""
+    return (2 * (bm * bk + bk * bn + bm * bn) * dtype_bytes
+            + bm * bn * 4)
+
+
 def choose_matmul_tiling(M: int, N: int, K: int, dtype_bytes: int = 2,
-                         vmem_budget: int = VMEM_BYTES // 2) -> MatmulTiling:
+                         vmem_budget: int = VMEM_LIMIT) -> MatmulTiling:
     """Block shapes for the NVDLA-adapted Pallas matmul kernel.
 
-    Working set per grid step = bm*bk + bk*bn + bm*bn (acc fp32).  Blocks are
-    MXU-aligned (multiples of 128 where the dim allows); the K (reduction)
-    dimension mirrors NVDLA's channel-block loop.
+    The working set (:func:`matmul_vmem_bytes`) must fit ``vmem_budget``.
+    Blocks are MXU-aligned (multiples of 128 where the dim allows); the K
+    (reduction) dimension mirrors NVDLA's channel-block loop.
     """
     def align(x, dim):
         if dim < MXU_DIM:
@@ -178,7 +190,7 @@ def choose_matmul_tiling(M: int, N: int, K: int, dtype_bytes: int = 2,
         for bn in (128, 256, 512):
             for bk in (128, 256, 512, 1024, 2048):
                 tbm, tbn, tbk = (min(bm, M), min(bn, N), min(bk, K))
-                ws = (tbm * tbk + tbk * tbn) * dtype_bytes + tbm * tbn * 4
+                ws = matmul_vmem_bytes(tbm, tbn, tbk, dtype_bytes)
                 if ws > vmem_budget:
                     continue
                 # prefer larger K blocks (fewer partial-sum round trips),

@@ -7,13 +7,11 @@ timeit-style best-of-k, then fit cost-backend parameters by least
 squares (:func:`repro.sim.backends.fit_linear_cost`) and build a
 measured :class:`repro.sim.backends.TableBackend`.
 
-On this CPU container the kernels run with ``interpret=True`` — the
-measured times are Python-interpreter magnitudes, wildly off the TPU
-roofline constants, which is exactly the point: the uncalibrated
-roofline error is enormous and the fitted error is small, and the same
-harness dropped onto a real TPU records ``backend="tpu"`` with honest
-Mosaic timings.  Every record carries the JAX backend it was measured
-on.
+On a TPU the kernels compile to Mosaic.  On a CPU host the caller must
+pass ``interpret=True``: the measured times are then Python-interpreter
+magnitudes, wildly off the TPU roofline constants, so the uncalibrated
+roofline error is enormous and the fitted error is small.  Every report
+carries the JAX backend and the interpret mode it was measured with.
 
 Used by ``tools/calibrate.py`` (CLI) and
 ``benchmarks/bench_calibration.py`` (the CI-gated artifact writer).
@@ -92,8 +90,8 @@ def _best_of(fn, repeat: int) -> float:
     return best
 
 
-def _measure_kernel(kernel: str, shape: Sequence[int],
-                    repeat: int) -> Dict:
+def _measure_kernel(kernel: str, shape: Sequence[int], repeat: int,
+                    interpret: bool) -> Dict:
     import jax
     from repro.kernels import ops
 
@@ -107,14 +105,15 @@ def _measure_kernel(kernel: str, shape: Sequence[int],
         M, N, K = shape
         a, b = rand(M, K), rand(K, N)
         flops, bytes_ = matmul_cost(M, N, K)
-        fn = lambda: ops.matmul(a, b).block_until_ready()  # noqa: E731
+        fn = lambda: ops.matmul(  # noqa: E731
+            a, b, interpret=interpret).block_until_ready()
     elif kernel == "attention":
         B, H, Hkv, S, D = shape
         q = rand(B, H, S, D)
         k, v = rand(B, Hkv, S, D), rand(B, Hkv, S, D)
         flops, bytes_ = attention_cost(B, H, Hkv, S, D)
         fn = lambda: ops.flash_attention(  # noqa: E731
-            q, k, v, bq=64, bk=64).block_until_ready()
+            q, k, v, bq=64, bk=64, interpret=interpret).block_until_ready()
     elif kernel == "mamba":
         b, S, d, N = shape
         x, dt = rand(b, S, d), rand(b, S, d)
@@ -122,7 +121,7 @@ def _measure_kernel(kernel: str, shape: Sequence[int],
         A, D = -jax.numpy.abs(rand(d, N)), rand(d)
         flops, bytes_ = mamba_cost(b, S, d, N)
         fn = lambda: ops.mamba_scan(  # noqa: E731
-            x, dt, Bm, C, A, D).block_until_ready()
+            x, dt, Bm, C, A, D, interpret=interpret).block_until_ready()
     else:
         raise ValueError(f"unknown kernel {kernel!r}; one of {KERNELS}")
     return {"kernel": kernel, "kind": kernel, "shape": list(shape),
@@ -131,7 +130,8 @@ def _measure_kernel(kernel: str, shape: Sequence[int],
 
 
 def measure(grid: str = "full", repeat: int = 3,
-            kernels: Sequence[str] = KERNELS) -> Tuple[List[Dict], Dict]:
+            kernels: Sequence[str] = KERNELS, *,
+            interpret: bool = False) -> Tuple[List[Dict], Dict]:
     """Time the Pallas kernels over the named shape grid.
 
     Returns ``(records, meta)``: per-shape records with the analytic
@@ -139,10 +139,10 @@ def measure(grid: str = "full", repeat: int = 3,
     naming the JAX backend and interpret mode the samples came from."""
     import jax
     grids = QUICK_GRIDS if grid == "quick" else FULL_GRIDS
-    records = [_measure_kernel(kernel, shape, repeat)
+    records = [_measure_kernel(kernel, shape, repeat, interpret)
                for kernel in kernels for shape in grids[kernel]]
-    backend = jax.default_backend()
-    return records, {"backend": backend, "interpret": backend != "tpu",
+    return records, {"backend": jax.default_backend(),
+                     "interpret": interpret,
                      "grid": grid, "repeat": repeat}
 
 

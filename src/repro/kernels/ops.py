@@ -1,38 +1,14 @@
-"""Jitted public wrappers for the Pallas kernels.
+"""Public entry points of the Pallas kernels.
 
-On a CPU container the kernels run with interpret=True (the kernel body
-executes in Python for correctness validation); on a real TPU the same
-calls compile to Mosaic.  The interpret default is resolved **per call**
-(not at import time): selecting a backend after this module imports, or
-running under a ``jax.default_device`` override, must flip the path.
+Each call compiles to Mosaic for the TPU.  ``interpret=True`` runs the
+kernel body in Python instead (correctness checks on a CPU host); it is
+never chosen from the backend, so a call without it on a host with no
+TPU fails instead of silently timing the interpreter.
+
+GQA/MQA KV enters ``flash_attention`` at its native (B, Hkv, S, D): the
+kernel's KV block index maps resolve the group head, so no broadcast is
+materialized and measured bytes match the model's accounting.
 """
-from __future__ import annotations
-
-import jax
-
-from repro.kernels.flash_attention import flash_attention as _flash
-from repro.kernels.mamba_scan import mamba_scan as _mamba
-from repro.kernels.nvdla_matmul import matmul as _matmul
-
-
-def _interpret() -> bool:
-    """Whether pallas_call should interpret: anything but a real TPU."""
-    return jax.default_backend() != "tpu"
-
-
-def matmul(a, b, **kw):
-    kw.setdefault("interpret", _interpret())
-    return _matmul(a, b, **kw)
-
-
-def flash_attention(q, k, v, *, causal=True, window=0, **kw):
-    # GQA/MQA KV stays at its native (B, Hkv, S, D): the kernel's KV
-    # block index maps resolve the group head, so no broadcast is
-    # materialized here and measured bytes match the model's accounting
-    kw.setdefault("interpret", _interpret())
-    return _flash(q, k, v, causal=causal, window=window, **kw)
-
-
-def mamba_scan(x, dt, B, C, A, D, **kw):
-    kw.setdefault("interpret", _interpret())
-    return _mamba(x, dt, B, C, A, D, **kw)
+from repro.kernels.flash_attention import flash_attention  # noqa: F401
+from repro.kernels.mamba_scan import mamba_scan  # noqa: F401
+from repro.kernels.nvdla_matmul import matmul  # noqa: F401

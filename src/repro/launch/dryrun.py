@@ -206,8 +206,7 @@ def run_cell(arch: str, shape: ShapeConfig, mesh, mesh_name: str,
         print(f"  compiled in {t_compile:.1f}s", flush=True)
         mem = compiled.memory_analysis()
         print("  memory_analysis done", flush=True)
-        from repro.core.compat import cost_analysis_dict
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         print("  cost_analysis done", flush=True)
         hlo_text = compiled.as_text()
         print(f"  as_text done ({len(hlo_text)/1e6:.1f} MB)", flush=True)

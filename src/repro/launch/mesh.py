@@ -1,9 +1,11 @@
-"""Production mesh builders.
+"""Mesh builders.
 
 Functions (not module-level constants) so importing this module never touches
-jax device state.  The dry-run launcher sets
+jax device state.  The launchers that run build their mesh from the local
+devices (``make_host_mesh``).  The 16x16 production mesh is only lowered and
+compiled: the dry-run launchers set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` BEFORE importing jax
-(see launch/dryrun.py) so these meshes can be built on a CPU-only host.
+(see launch/dryrun.py) so it can be built on a CPU-only host.
 """
 from __future__ import annotations
 
@@ -22,8 +24,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     devices = jax.devices()[:n]
     if len(devices) < n:
         raise RuntimeError(
-            f"need {n} devices, have {len(devices)}; run under "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=512")
+            f"the production mesh needs {n} devices, have {len(devices)}. "
+            f"It is for lowering and compiling only: repro.launch.dryrun "
+            f"fakes 512 CPU devices for it.  To run on the chips this "
+            f"process holds, build the mesh with make_host_mesh")
     import numpy as np
     dev_array = np.asarray(devices).reshape(shape)
     return jax.sharding.Mesh(dev_array, axes)

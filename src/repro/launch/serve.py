@@ -1,75 +1,148 @@
-"""Serving launcher: continuous batching of generation requests against a
-sharded KV cache.
+"""Serving launcher: static batches of greedy generation requests.
 
-A minimal production-shaped server loop: a request queue feeds fixed-size
-decode batches; finished sequences are swapped out and their cache slots
-recycled (slot-indexed batch).  On this container it runs the reduced config
-on the local device; the production mesh decode path is exercised by the
-dry-run decode cells.
+A request queue feeds fixed-size batches.  Each batch is prefilled in
+one jitted call (``make_prefill_step``), then decoded one token per
+jitted step (``make_decode_step``) against its KV cache.  A short last
+batch is padded with copies of its last prompt, so prefill and decode
+each compile once.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch gemma3_1b --requests 8
+  python -m repro.launch.serve --arch granite_moe_1b_a400m
+  PYTHONPATH=src python -m repro.launch.serve --arch gemma3_1b --smoke
+
+The full config runs by default and needs a TPU: without one the
+launcher exits instead of going on on the CPU.  ``--smoke`` selects the
+reduced preset of the same family, which runs on any device.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
+from repro.core.config import ModelConfig
+from repro.launch.chip import (CompileTimer, program_bytes, require_tpu,
+                               use_compile_cache)
 from repro.models import transformer as T
-from repro.serve import make_decode_step
+from repro.serve import make_decode_step, make_prefill_step
+
+
+@dataclass
+class ServeResult:
+    tokens: np.ndarray      # (n_requests, max_new) greedy continuations
+    cache: Any              # last batch's cache after its last decode step
+    next_pos: int           # cache position of the last generated token
+    compile_s: float        # tracing + lowering + compiling, all steps
+    decode_s: float         # timed decode steps, ends in block_until_ready
+    n_decode_steps: int     # decode steps inside ``decode_s``
+    memory: dict            # "prefill"/"decode": compiled memory_analysis()
+
+
+def model_inputs(cfg: ModelConfig, tokens) -> dict:
+    """The prefill batch for ``tokens``; enc-dec frames and VLM patches
+    are constant stand-ins for the (stub) encoder and vision tower."""
+    B = tokens.shape[0]
+    batch = {"tokens": tokens}
+    if cfg.family == "encdec":
+        batch["frames"] = jnp.full((B, cfg.encoder.n_ctx, cfg.d_model), .1,
+                                   jnp.float32)
+    if cfg.family == "vlm":
+        batch["patches"] = jnp.full((B, cfg.n_patches, cfg.d_model), .1,
+                                    jnp.float32)
+    return batch
+
+
+def serve(cfg: ModelConfig, params, prompts: Sequence[np.ndarray], *,
+          batch: int, max_new: int, emit=print) -> ServeResult:
+    """Generate ``max_new`` greedy tokens for each prompt, in static
+    batches of ``batch``.  All prompts have one length.
+
+    The first decode step of each batch runs outside the timed window,
+    since on the first batch it compiles; ``decode_s`` covers the other
+    ``max_new - 2`` steps of every batch."""
+    if max_new < 3:
+        raise ValueError(f"max_new={max_new}: needs 3 or more, since two "
+                         "tokens come from untimed steps")
+    prompt_len = len(prompts[0])
+    pos0 = prompt_len + (cfg.n_patches if cfg.family == "vlm" else 0)
+    prefill = jax.jit(make_prefill_step(cfg, max_seq=pos0 + max_new))
+    decode = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
+    queue = list(prompts)
+    outs = []
+    decode_s = 0.0
+    with CompileTimer() as timer:
+        while queue:
+            rows = queue[:batch]
+            queue = queue[batch:]
+            padded = rows + [rows[-1]] * (batch - len(rows))
+            inputs = model_inputs(cfg, jnp.asarray(np.stack(padded)))
+            logits, cache = prefill(params, inputs)
+            tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+            toks = [tok]
+            tok, cache = decode(params, cache, tok, jnp.int32(pos0))
+            toks.append(tok)
+            tok.block_until_ready()
+            t0 = time.perf_counter()
+            for i in range(2, max_new):
+                tok, cache = decode(params, cache, tok,
+                                    jnp.int32(pos0 + i - 1))
+                toks.append(tok)
+            tok.block_until_ready()
+            decode_s += time.perf_counter() - t0
+            out = np.asarray(jnp.concatenate(toks, 1))[:len(rows)]
+            outs.append(out)
+            emit(f"[batch] finished {len(rows)} requests "
+                 f"({sum(len(o) for o in outs)}/{len(prompts)}); sample "
+                 f"continuation: {out[0][:8]}")
+    # lowering again at the same shapes reuses the executables compiled
+    # above: this reads their memory, it compiles nothing
+    lowered = {"prefill": prefill.lower(params, inputs),
+               "decode": decode.lower(params, cache, tok, jnp.int32(pos0))}
+    n_batches = len(outs)
+    return ServeResult(tokens=np.concatenate(outs), cache=cache,
+                       next_pos=pos0 + max_new - 1,
+                       compile_s=timer.seconds, decode_s=decode_s,
+                       n_decode_steps=n_batches * (max_new - 2),
+                       memory={k: low.compile().memory_analysis()
+                               for k, low in lowered.items()})
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="gemma3_1b")
+    ap.add_argument("--arch", default="granite_moe_1b_a400m")
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced preset of the same family")
     ap.add_argument("--requests", type=int, default=8)
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--max-new", type=int, default=12)
+    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--prompt-len", type=int, default=2048)
+    ap.add_argument("--max-new", type=int, default=64)
     args = ap.parse_args()
 
-    cfg = get_smoke_config(args.arch)
+    if not args.smoke:
+        require_tpu()
+    use_compile_cache()
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
-    queue = [rng.integers(0, cfg.vocab, (args.prompt_len,)).astype(np.int32)
-             for _ in range(args.requests)]
-    decode = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
-    max_seq = args.prompt_len + cfg.n_patches + args.max_new
-
-    done = 0
-    t0 = time.time()
-    while queue:
-        batch_prompts = [queue.pop(0) for _ in
-                         range(min(args.batch, len(queue)))]
-        B = len(batch_prompts)
-        batch = {"tokens": jnp.asarray(np.stack(batch_prompts))}
-        if cfg.family == "encdec":
-            batch["frames"] = jnp.ones(
-                (B, cfg.encoder.n_ctx, cfg.d_model), jnp.float32) * .1
-        if cfg.family == "vlm":
-            batch["patches"] = jnp.ones(
-                (B, cfg.n_patches, cfg.d_model), jnp.float32) * .1
-        logits, cache = T.prefill_forward(cfg, params, batch,
-                                          max_seq=max_seq)
-        tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-        pos0 = args.prompt_len + (cfg.n_patches if cfg.family == "vlm"
-                                  else 0)
-        outs = [tok]
-        for i in range(args.max_new - 1):
-            tok, cache = decode(params, cache, tok,
-                                jnp.asarray(pos0 + i, jnp.int32))
-            outs.append(tok)
-        done += B
-        print(f"[batch] finished {B} requests "
-              f"({done}/{args.requests}); sample continuation: "
-              f"{np.asarray(jnp.concatenate(outs, 1))[0][:8]}")
-    dt = time.time() - t0
-    print(f"served {done} requests in {dt:.2f}s "
-          f"({done * args.max_new / dt:.1f} tok/s aggregate)")
+    prompts = list(rng.integers(0, cfg.vocab, (args.requests,
+                                               args.prompt_len),
+                                dtype=np.int32))
+    t0 = time.perf_counter()
+    r = serve(cfg, params, prompts, batch=args.batch, max_new=args.max_new)
+    dt = time.perf_counter() - t0
+    dev = jax.devices()[0]
+    print(f"served {len(r.tokens)} requests in {dt:.2f}s on "
+          f"{dev.platform} {dev.device_kind} x{len(jax.devices())}: "
+          f"compile {r.compile_s:.1f}s, decode "
+          f"{1e3 * r.decode_s / r.n_decode_steps:.2f} ms/step "
+          f"(batch {args.batch}); compiled bytes prefill "
+          f"{program_bytes(r.memory['prefill'])}, decode "
+          f"{program_bytes(r.memory['decode'])}")
 
 
 if __name__ == "__main__":

@@ -1,11 +1,16 @@
-"""Production training launcher: builds the mesh, shards state per the rule
-engine, and runs the train loop with fault-tolerant checkpointing.
+"""Training launcher: builds the mesh, shards state per the rule engine,
+and runs the train loop with fault-tolerant checkpointing.
 
-On real hardware:
   python -m repro.launch.train --arch tinyllama_1_1b --shape train_4k
-On this container it runs reduced configs on the single local device
-(``--smoke``); the production mesh path is exercised (lower+compile) by
-``repro.launch.dryrun``.
+  PYTHONPATH=src python -m repro.launch.train --smoke
+
+The mesh is data-parallel over the chips this process holds, and the full
+config needs TPUs: without them the launcher exits instead of going on
+on the CPU.  The default cell (tinyllama_1_1b, train_4k: 256 x 4096
+tokens a step, about 13 GB of parameters, gradients and AdamW state
+before any activation) needs more than one v5e chip's 16 GB.  ``--smoke`` runs the
+reduced preset (batch 4, 64 tokens) on any device.  The 16x16 production mesh is lowered and compiled, not run,
+by ``repro.launch.dryrun``.
 
 Fault-tolerance posture (DESIGN.md §4): resume from the newest committed
 checkpoint (``--resume``), async saves off the training thread, elastic
@@ -33,7 +38,8 @@ from repro.core.config import SHAPE_BY_NAME
 from repro.data import DataPipeline
 from repro.dist import context as dist_ctx
 from repro.dist.sharding import rules_for, set_active_rules
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.chip import require_tpu, use_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.optim import adamw_init
 from repro.train import TrainConfig, make_train_step
@@ -74,7 +80,6 @@ def main():
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config on local devices")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--resume", action="store_true")
@@ -97,12 +102,13 @@ def main():
     shape = SHAPE_BY_NAME[args.shape]
     if args.smoke:
         cfg = get_smoke_config(args.arch)
-        mesh = make_host_mesh(1, 1)
         batch, seq = 4, 64
     else:
+        require_tpu()
         cfg = get_config(args.arch)
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
         batch, seq = shape.global_batch, shape.seq_len
+    use_compile_cache()
+    mesh = make_host_mesh(len(jax.devices()), 1)
 
     rules = rules_for(cfg, shape, mesh)
     set_active_rules(rules)

@@ -197,12 +197,17 @@ def sweep(program: Program, configs: Sequence[EngineConfig], *,
                 configs))
     if executor == "process":
         import concurrent.futures
+        import multiprocessing
         from concurrent.futures.process import BrokenProcessPool
         import os
         nw = max_workers or min(len(configs), os.cpu_count() or 1)
         try:
+            # spawn, not fork: a forked child of a process whose JAX
+            # threads are running can deadlock
             with concurrent.futures.ProcessPoolExecutor(
-                    max_workers=nw, initializer=_proc_init,
+                    max_workers=nw,
+                    mp_context=multiprocessing.get_context("spawn"),
+                    initializer=_proc_init,
                     initargs=(program, model_flops, host_s)) as ex:
                 return list(ex.map(_proc_run, configs))
         except (BrokenProcessPool, OSError, ImportError,

@@ -22,8 +22,9 @@ Contract layers:
   ``chain_params_for``, ``batched``/``optimize``) refuses non-roofline
   backends with ``Unsupported`` instead of mispricing them.
 * **bugfix regressions** — ``costmodel._has_jax`` warns exactly once on
-  a broken (not merely absent) jax; ``repro.kernels.ops`` resolves
-  interpret per call, not at import; GQA attention passes KV to the
+  a broken (not merely absent) jax; ``repro.kernels.ops`` interprets
+  only when the caller passes ``interpret=True``, never on its own
+  because of the backend; GQA attention passes KV to the
   kernel at its native ``(B, Hkv, S, D)`` instead of materializing the
   broadcast.
 """
@@ -466,41 +467,50 @@ def test_has_jax_quiet_when_absent_or_present(monkeypatch):
     del jax
 
 
-def test_interpret_resolved_per_call(monkeypatch):
+def test_interpret_only_when_asked():
+    """Kernels interpret only when the caller passes ``interpret=True``.
+    Off the TPU a call without it fails rather than falling back to the
+    interpreter, so no timing of the interpreter passes for a chip's."""
     jax = pytest.importorskip("jax")
-    from repro.kernels import ops
-    seen = []
-    monkeypatch.setattr(ops, "_matmul",
-                        lambda a, b, **kw: seen.append(kw) or a)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    ops.matmul(None, None)
-    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-    ops.matmul(None, None)
-    # the regression: an import-time INTERPRET constant froze the first
-    # answer; per-call resolution must see the backend flip
-    assert [kw["interpret"] for kw in seen] == [False, True]
-    ops.matmul(None, None, interpret=False)         # explicit kw wins
-    assert seen[-1]["interpret"] is False
+    if jax.default_backend() == "tpu":
+        pytest.skip("checks the refusal on a host with no TPU")
+    import jax.numpy as jnp
+    from repro.kernels import ops, ref
+    a = jax.random.normal(jax.random.PRNGKey(0), (128, 256), jnp.float32)
+    b = jax.random.normal(jax.random.PRNGKey(1), (256, 128), jnp.float32)
+    with pytest.raises(ValueError, match="interpret"):
+        ops.matmul(a, b)
+    np.testing.assert_allclose(np.asarray(ops.matmul(a, b, interpret=True)),
+                               np.asarray(ref.matmul_ref(a, b)),
+                               rtol=1e-4, atol=1e-3)
 
 
 def test_gqa_kv_reaches_kernel_unmaterialized(monkeypatch):
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
+    from repro.kernels import flash_attention as fa
     from repro.kernels import ops
     B, H, Hkv, S, D = 1, 4, 2, 64, 16
     seen = {}
+    real = fa.pl.pallas_call
 
-    def spy(q, k, v, **kw):
-        seen["k"], seen["v"] = k.shape, v.shape
-        return q
-    monkeypatch.setattr(ops, "_flash", spy)
+    def spy(*a, **kw):
+        call = real(*a, **kw)
+
+        def run(q, k, v):
+            seen["k"], seen["v"] = k.shape, v.shape
+            return call(q, k, v)
+        return run
+    monkeypatch.setattr(fa.pl, "pallas_call", spy)
+    fa.flash_attention.clear_cache()        # trace again, through the spy
     q = jnp.zeros((B, H, S, D))
     kv = jnp.zeros((B, Hkv, S, D))
-    ops.flash_attention(q, kv, kv)
-    # the regression: the wrapper used to jnp.broadcast_to KV to the full
-    # (B, H, S, D) before the kernel ever saw it
-    assert seen["k"] == (B, Hkv, S, D)
-    assert seen["v"] == (B, Hkv, S, D)
+    ops.flash_attention(q, kv, kv, interpret=True)
+    # the regression: a wrapper used to jnp.broadcast_to KV to the full
+    # (B, H, S, D) before the kernel ever saw it; the kernel gets the
+    # (B*Hkv, S, D) rows and resolves the group in its index maps
+    assert seen["k"] == (B * Hkv, S, D)
+    assert seen["v"] == (B * Hkv, S, D)
 
 
 def test_gqa_native_kernel_matches_repeated_kv_reference():
@@ -513,9 +523,9 @@ def test_gqa_native_kernel_matches_repeated_kv_reference():
     q = jax.random.normal(kq, (B, H, S, D), jnp.float32)
     k = jax.random.normal(kk, (B, Hkv, S, D), jnp.float32)
     v = jax.random.normal(kv_, (B, Hkv, S, D), jnp.float32)
-    native = ops.flash_attention(q, k, v, bq=64, bk=64)
+    native = ops.flash_attention(q, k, v, bq=64, bk=64, interpret=True)
     repeated = ops.flash_attention(q, jnp.repeat(k, H // Hkv, axis=1),
                                    jnp.repeat(v, H // Hkv, axis=1),
-                                   bq=64, bk=64)
+                                   bq=64, bk=64, interpret=True)
     np.testing.assert_allclose(np.asarray(native), np.asarray(repeated),
                                rtol=1e-5, atol=1e-5)

@@ -21,8 +21,7 @@ def test_scan_trip_count_unsampled():
     assert abs(res["dot_flops"] - 10 * per_iter) / (10 * per_iter) < 0.05
     assert res["n_while"] >= 1
     # XLA's own cost_analysis counts the body once — we must exceed it ~10x
-    from repro.core.compat import cost_analysis_dict
-    ca = cost_analysis_dict(jax.jit(f).lower(xs, ws).compile())
+    ca = jax.jit(f).lower(xs, ws).compile().cost_analysis()
     assert res["dot_flops"] > 5 * ca["flops"]
 
 

@@ -1,0 +1,86 @@
+"""Compiles for a described TPU v5e, with no chip attached: the Pallas
+kernels at the widths ``chip_smoke.py`` runs, and granite's full-width
+decode step.  The TPU compiler refuses here what interpret mode accepts
+(a slice the tiling cannot prove aligned, more VMEM than a kernel may
+use) and a program that does not fit the chip's 16 GB of HBM.
+
+The topology is described inside a fixture, never while a module is
+imported: one process at a time may load the TPU library, so only the
+worker that runs this file does.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+from repro.launch.chip import program_bytes
+from repro.models import transformer as T
+from repro.serve import make_decode_step
+
+GRANITE = get_config("granite_moe_1b_a400m")
+BATCH, PROMPT_LEN, MAX_NEW = 8, 2048, 64      # chip_smoke.py's serve phase
+HBM_BYTES = 16 * 10**9                        # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # any failure: no TPU compiler to describe it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel_case(name):
+    """(kernel, argument shapes) at chip_smoke.py's widths."""
+    g, bf, f32 = GRANITE, jnp.bfloat16, jnp.float32
+    if name == "matmul":
+        M, K, N = BATCH * PROMPT_LEN, g.d_model, g.moe.d_ff_expert
+        return ops.matmul, [((M, K), bf), ((K, N), bf)]
+    if name == "matmul_f32_4096":     # default tiling at the VMEM limit
+        return ops.matmul, [((4096, 4096), f32), ((4096, 4096), f32)]
+    if name == "flash_attention":
+        H, Hkv, D = g.n_heads, g.n_kv_heads, g.resolved_head_dim
+        return ops.flash_attention, [((2, H, PROMPT_LEN, D), bf),
+                                     ((2, Hkv, PROMPT_LEN, D), bf),
+                                     ((2, Hkv, PROMPT_LEN, D), bf)]
+    d, n = 8192, 16                   # mamba_scan at d_inner 8192
+    return ops.mamba_scan, [((1, PROMPT_LEN, d), bf), ((1, PROMPT_LEN, d), bf),
+                            ((1, PROMPT_LEN, n), bf), ((1, PROMPT_LEN, n), bf),
+                            ((d, n), f32), ((d,), f32)]
+
+
+@pytest.mark.parametrize("name", ["matmul", "matmul_f32_4096",
+                                  "flash_attention", "mamba_scan"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    kernel, shapes = _kernel_case(name)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(kernel).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_granite_decode_compiles_for_v5e(one_chip):
+    cfg = GRANITE
+    max_seq = PROMPT_LEN + MAX_NEW
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda k: T.init_params(cfg, k)[0], jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: T.init_cache(cfg, BATCH, max_seq)[0]))
+    tokens = jax.ShapeDtypeStruct((BATCH, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(make_decode_step(cfg), donate_argnums=(1,)).lower(
+        params, cache, tokens, pos).compile()
+    total = program_bytes(compiled.memory_analysis())
+    assert total < HBM_BYTES, total

@@ -8,8 +8,11 @@ or writes — the calibration report.  The CI-gated artifact writer is
 ``benchmarks/bench_calibration.py``; this is the standalone harness for
 poking at grids and repeats:
 
-    PYTHONPATH=src python tools/calibrate.py --grid quick
+    PYTHONPATH=src python tools/calibrate.py --grid quick --interpret
     PYTHONPATH=src python tools/calibrate.py --repeat 5 --out cal.json
+
+``--interpret`` runs the kernel bodies in Python, for a host with no TPU;
+without it the kernels compile to Mosaic and need the chip.
 """
 from __future__ import annotations
 
@@ -31,12 +34,15 @@ def main():
     ap.add_argument("--kernels", nargs="+", default=list(calibrate.KERNELS),
                     choices=list(calibrate.KERNELS),
                     help="subset of kernels to measure")
+    ap.add_argument("--interpret", action="store_true",
+                    help="interpret the kernels (a host with no TPU)")
     ap.add_argument("--out", type=pathlib.Path, default=None,
                     help="write the report JSON here instead of stdout")
     args = ap.parse_args()
 
     records, meta = calibrate.measure(grid=args.grid, repeat=args.repeat,
-                                      kernels=args.kernels)
+                                      kernels=args.kernels,
+                                      interpret=args.interpret)
     report = calibrate.build_report(records, meta)
     text = json.dumps(report, indent=2, default=float) + "\n"
     if args.out:
