@@ -23,6 +23,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.config import ModelConfig
@@ -64,7 +65,15 @@ def serve(cfg: ModelConfig, params, prompts: Sequence[np.ndarray], *,
 
     The first decode step of each batch runs outside the timed window,
     since on the first batch it compiles; ``decode_s`` covers the other
-    ``max_new - 2`` steps of every batch."""
+    ``max_new - 2`` steps of every batch.
+
+    Each batch's phases are profiler spans that carry the batch's index
+    as ``batch``: ``serve/admit`` (pad, stack, move to the device),
+    ``serve/prefill`` (dispatch prefill and the first token's argmax),
+    ``serve/decode`` (the decode steps, up to the last token's being
+    ready) and ``serve/collect`` (the tokens to the host).  On the first
+    batch, prefill and decode also trace and compile their steps.
+    ``emit`` runs between batches, outside the spans."""
     if max_new < 3:
         raise ValueError(f"max_new={max_new}: needs 3 or more, since two "
                          "tokens come from untimed steps")
@@ -77,25 +86,30 @@ def serve(cfg: ModelConfig, params, prompts: Sequence[np.ndarray], *,
     decode_s = 0.0
     with CompileTimer() as timer:
         while queue:
-            rows = queue[:batch]
-            queue = queue[batch:]
-            padded = rows + [rows[-1]] * (batch - len(rows))
-            inputs = model_inputs(cfg, jnp.asarray(np.stack(padded)))
-            logits, cache = prefill(params, inputs)
-            tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-            toks = [tok]
-            tok, cache = decode(params, cache, tok, jnp.int32(pos0))
-            toks.append(tok)
-            tok.block_until_ready()
-            t0 = time.perf_counter()
-            for i in range(2, max_new):
-                tok, cache = decode(params, cache, tok,
-                                    jnp.int32(pos0 + i - 1))
+            b = len(outs)
+            with TraceAnnotation("serve/admit", batch=b):
+                rows = queue[:batch]
+                queue = queue[batch:]
+                padded = rows + [rows[-1]] * (batch - len(rows))
+                inputs = model_inputs(cfg, jnp.asarray(np.stack(padded)))
+            with TraceAnnotation("serve/prefill", batch=b):
+                logits, cache = prefill(params, inputs)
+                tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+                toks = [tok]
+            with TraceAnnotation("serve/decode", batch=b):
+                tok, cache = decode(params, cache, tok, jnp.int32(pos0))
                 toks.append(tok)
-            tok.block_until_ready()
-            decode_s += time.perf_counter() - t0
-            out = np.asarray(jnp.concatenate(toks, 1))[:len(rows)]
-            outs.append(out)
+                tok.block_until_ready()
+                t0 = time.perf_counter()
+                for i in range(2, max_new):
+                    tok, cache = decode(params, cache, tok,
+                                        jnp.int32(pos0 + i - 1))
+                    toks.append(tok)
+                tok.block_until_ready()
+                decode_s += time.perf_counter() - t0
+            with TraceAnnotation("serve/collect", batch=b):
+                out = np.asarray(jnp.concatenate(toks, 1))[:len(rows)]
+                outs.append(out)
             emit(f"[batch] finished {len(rows)} requests "
                  f"({sum(len(o) for o in outs)}/{len(prompts)}); sample "
                  f"continuation: {out[0][:8]}")

@@ -214,6 +214,7 @@ def decode_attention(q, k_cache, v_cache, *, pos, window=0, k_pos=None):
 # standard (GQA) attention layer forward
 
 
+@jax.named_scope("attention")
 def gqa_forward(p, x, cos, sin, *, cfg: ModelConfig, causal=True, window=0,
                 q_offset=0, xa=None, static_window=None):
     """Full-sequence attention (train/prefill).  Returns (out, (k, v)).
@@ -243,6 +244,7 @@ def gqa_forward(p, x, cos, sin, *, cfg: ModelConfig, causal=True, window=0,
     return tp_project(out, p["o"]), (k, v)
 
 
+@jax.named_scope("attention")
 def gqa_decode(p, x, cache_k, cache_v, cos, sin, *, cfg: ModelConfig, pos,
                window=0, xa_kv=None, static_window=None):
     """One-token decode.  x: (B, 1, d).  cache_[kv]: (B, Hkv, S, hd).
@@ -264,16 +266,18 @@ def gqa_decode(p, x, cache_k, cache_v, cos, sin, *, cfg: ModelConfig, pos,
     if cos is not None:
         q = _rope_heads(q, cos, sin)
         k_new = _rope_heads(k_new, cos, sin)
-    cache_k = jax.lax.dynamic_update_slice(cache_k, k_new.astype(cache_k.dtype),
-                                           (0, 0, pos, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache_v, v_new.astype(cache_v.dtype),
-                                           (0, 0, pos, 0))
+    with jax.named_scope("kv_cache"):
+        cache_k = jax.lax.dynamic_update_slice(
+            cache_k, k_new.astype(cache_k.dtype), (0, 0, pos, 0))
+        cache_v = jax.lax.dynamic_update_slice(
+            cache_v, v_new.astype(cache_v.dtype), (0, 0, pos, 0))
     if static_window:
         S = cache_k.shape[2]
         w = min(static_window, S)
-        start = jnp.clip(pos - w + 1, 0, S - w)
-        k_win = jax.lax.dynamic_slice_in_dim(cache_k, start, w, 2)
-        v_win = jax.lax.dynamic_slice_in_dim(cache_v, start, w, 2)
+        with jax.named_scope("kv_cache"):
+            start = jnp.clip(pos - w + 1, 0, S - w)
+            k_win = jax.lax.dynamic_slice_in_dim(cache_k, start, w, 2)
+            v_win = jax.lax.dynamic_slice_in_dim(cache_v, start, w, 2)
         out = decode_attention(q, k_win, v_win, pos=pos,
                                k_pos=start + jnp.arange(w))
     else:
@@ -292,6 +296,7 @@ def _rope_heads(x, cos, sin):
 # MLA (DeepSeek V2) — compressed KV cache
 
 
+@jax.named_scope("attention")
 def mla_forward(p, x, cos, sin, *, cfg: ModelConfig, q_offset=0):
     """Train/prefill MLA, naive (expanded) form.  Returns (out, (c_kv, k_rope))."""
     m = cfg.mla
@@ -319,6 +324,7 @@ def mla_forward(p, x, cos, sin, *, cfg: ModelConfig, q_offset=0):
     return out @ p["o"], (c_kv, k_rope)
 
 
+@jax.named_scope("attention")
 def mla_decode(p, x, cache_ckv, cache_krope, cos, sin, *, cfg: ModelConfig, pos):
     """Absorbed-matmul MLA decode: attention runs in the compressed space.
     cache_ckv: (B, S, lora); cache_krope: (B, S, dr)."""
@@ -332,10 +338,11 @@ def mla_decode(p, x, cache_ckv, cache_krope, cos, sin, *, cfg: ModelConfig, pos)
     kv = x @ p["kv_a"]
     c_new = rmsnorm(kv[..., :R], p["kv_norm"])             # (B, 1, R)
     kr_new = _rope_heads(kv[:, None, :, R:], cos, sin)[:, 0]
-    cache_ckv = jax.lax.dynamic_update_slice(
-        cache_ckv, c_new.astype(cache_ckv.dtype), (0, pos, 0))
-    cache_krope = jax.lax.dynamic_update_slice(
-        cache_krope, kr_new.astype(cache_krope.dtype), (0, pos, 0))
+    with jax.named_scope("kv_cache"):
+        cache_ckv = jax.lax.dynamic_update_slice(
+            cache_ckv, c_new.astype(cache_ckv.dtype), (0, pos, 0))
+        cache_krope = jax.lax.dynamic_update_slice(
+            cache_krope, kr_new.astype(cache_krope.dtype), (0, pos, 0))
     wkb = p["kv_b"].reshape(R, H, dn + dv)
     w_k, w_v = wkb[..., :dn], wkb[..., dn:]
     # absorb: q into compressed space

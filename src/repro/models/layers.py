@@ -113,6 +113,7 @@ def _act(name, x):
     return jax.nn.gelu(x, approximate=True)  # plain gelu
 
 
+@jax.named_scope("mlp")
 def mlp_apply(p, x, activation):
     from repro.dist.tp import tp_project
     up = x @ p["up"]

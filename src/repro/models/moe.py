@@ -51,6 +51,7 @@ def moe_init(rng, cfg: ModelConfig, dtype=jnp.bfloat16):
     return p
 
 
+@jax.named_scope("route")
 def _route(x32, router_w, n_experts, top_k):
     """Returns (weights (T,k) f32, experts (T,k) i32, aux dict)."""
     logits = x32 @ router_w                                # (T, E) f32
@@ -93,6 +94,34 @@ def _dispatch_indices(e_idx, n_experts, e_start, e_local, capacity):
     return buf_token.reshape(e_local, capacity), slot_of.reshape(T, k)
 
 
+@jax.named_scope("dispatch")
+def _dispatch(x, e_idx, n_experts, e_start, e_local, capacity):
+    """x: (T, d) -> (capacity buffers (e_local, capacity, d) of the local
+    experts' tokens, slot_of (T, k) as ``_dispatch_indices`` gives it)."""
+    d = x.shape[1]
+    buf_token, slot_of = _dispatch_indices(e_idx, n_experts, e_start, e_local,
+                                           capacity)
+    xpad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)], axis=0)
+    xb = xpad[buf_token.reshape(-1)].reshape(e_local, capacity, d)
+    return xb, slot_of
+
+
+@jax.named_scope("combine")
+def _combine(yb, w, slot_of, psum_axis, dtype):
+    """Each token's expert outputs (yb: (e_local, capacity, d)) gathered
+    back from its slots and summed with its routing weights w (T, k)."""
+    d = yb.shape[-1]
+    ypad = jnp.concatenate([yb.reshape(-1, d), jnp.zeros((1, d), yb.dtype)],
+                           axis=0)
+    out = jnp.zeros((w.shape[0], d), jnp.float32)
+    for j in range(w.shape[1]):
+        out = out + w[:, j:j + 1] * ypad[slot_of[:, j]].astype(jnp.float32)
+    if psum_axis is not None:
+        out = jax.lax.psum(out, psum_axis)
+    return out.astype(dtype)
+
+
+@jax.named_scope("experts")
 def _expert_ffn(p_gate, p_up, p_down, xb, activation="swiglu"):
     """xb: (E_local, C, d) -> (E_local, C, d)."""
     g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xb, p_gate))
@@ -103,30 +132,23 @@ def _expert_ffn(p_gate, p_up, p_down, xb, activation="swiglu"):
 def _moe_local(p, x, cfg: ModelConfig, ep_rank, ep_size, psum_axis):
     """Per-shard MoE.  x: (T, d) local tokens.  Returns (out (T, d), aux)."""
     e = cfg.moe
-    T, d = x.shape
+    T = x.shape[0]
     e_local = e.n_experts // ep_size
     e_start = ep_rank * e_local
     capacity = max(1, math.ceil(T * e.top_k * e.capacity_factor / e.n_experts))
 
     w, idx, aux = _route(x.astype(jnp.float32), p["router"], e.n_experts,
                          e.top_k)
-    buf_token, slot_of = _dispatch_indices(idx, e.n_experts, e_start, e_local,
-                                           capacity)
-    xpad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)], axis=0)
-    xb = xpad[buf_token.reshape(-1)].reshape(e_local, capacity, d)
-    gate_l = jax.lax.dynamic_slice_in_dim(p["gate"], e_start, e_local, 0)
-    up_l = jax.lax.dynamic_slice_in_dim(p["up"], e_start, e_local, 0)
-    down_l = jax.lax.dynamic_slice_in_dim(p["down"], e_start, e_local, 0)
-    yb = _expert_ffn(gate_l, up_l, down_l, xb).reshape(e_local * capacity, d)
-    ypad = jnp.concatenate([yb, jnp.zeros((1, d), yb.dtype)], axis=0)
-    out = jnp.zeros((T, d), jnp.float32)
-    for j in range(e.top_k):
-        out = out + w[:, j:j + 1] * ypad[slot_of[:, j]].astype(jnp.float32)
-    if psum_axis is not None:
-        out = jax.lax.psum(out, psum_axis)
-    return out.astype(x.dtype), aux
+    xb, slot_of = _dispatch(x, idx, e.n_experts, e_start, e_local, capacity)
+    with jax.named_scope("experts"):
+        gate_l = jax.lax.dynamic_slice_in_dim(p["gate"], e_start, e_local, 0)
+        up_l = jax.lax.dynamic_slice_in_dim(p["up"], e_start, e_local, 0)
+        down_l = jax.lax.dynamic_slice_in_dim(p["down"], e_start, e_local, 0)
+    yb = _expert_ffn(gate_l, up_l, down_l, xb)
+    return _combine(yb, w, slot_of, psum_axis, x.dtype), aux
 
 
+@jax.named_scope("moe")
 def moe_apply(p, x, cfg: ModelConfig):
     """x: (B, S, d) -> (out (B, S, d), aux losses dict).
 
@@ -182,25 +204,15 @@ def moe_apply(p, x, cfg: ModelConfig):
 def _moe_local_shard(p, x, cfg, ep_rank, ep_size, psum_axis):
     """Like _moe_local but expert params are ALREADY the local shard."""
     e = cfg.moe
-    T, d = x.shape
+    T = x.shape[0]
     e_local = e.n_experts // ep_size
     e_start = ep_rank * e_local
     capacity = max(1, math.ceil(T * e.top_k * e.capacity_factor / e.n_experts))
     w, idx, aux = _route(x.astype(jnp.float32), p["router"], e.n_experts,
                          e.top_k)
-    buf_token, slot_of = _dispatch_indices(idx, e.n_experts, e_start, e_local,
-                                           capacity)
-    xpad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)], axis=0)
-    xb = xpad[buf_token.reshape(-1)].reshape(e_local, capacity, d)
+    xb, slot_of = _dispatch(x, idx, e.n_experts, e_start, e_local, capacity)
     yb = _expert_ffn(p["gate"], p["up"], p["down"], xb)
-    ypad = jnp.concatenate([yb.reshape(e_local * capacity, d),
-                            jnp.zeros((1, d), yb.dtype)], axis=0)
-    out = jnp.zeros((T, d), jnp.float32)
-    for j in range(e.top_k):
-        out = out + w[:, j:j + 1] * ypad[slot_of[:, j]].astype(jnp.float32)
-    if psum_axis is not None:
-        out = jax.lax.psum(out, psum_axis)
-    return out.astype(x.dtype), aux
+    return _combine(yb, w, slot_of, psum_axis, x.dtype), aux
 
 
 # ---------------------------------------------------------------------------
@@ -219,18 +231,23 @@ def moe_apply_einsum(p, x, cfg: ModelConfig):
     w, idx, aux = _route(xf.astype(jnp.float32), p["router"], e.n_experts,
                          e.top_k)
     # dispatch tensor (T, E, C)
-    onehot_e = jax.nn.one_hot(idx, e.n_experts, dtype=jnp.float32)  # (T,k,E)
-    pos = jnp.cumsum(onehot_e.reshape(T * e.top_k, e.n_experts), axis=0) - 1
-    pos = pos.reshape(T, e.top_k, e.n_experts)
-    pos_tk = jnp.sum(pos * onehot_e, axis=-1)              # (T, k)
-    within = (pos_tk < capacity)[..., None]                # (T, k, 1)
-    pos_onehot = jax.nn.one_hot(pos_tk, capacity, dtype=jnp.float32)
-    disp = jnp.einsum("tke,tkc->tec", onehot_e * within, pos_onehot)
-    comb = jnp.einsum("tke,tkc,tk->tec", onehot_e * within, pos_onehot, w)
-    xb = jnp.einsum("tec,td->ecd", disp, xf.astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope("dispatch"):
+        onehot_e = jax.nn.one_hot(idx, e.n_experts,
+                                  dtype=jnp.float32)           # (T,k,E)
+        pos = jnp.cumsum(onehot_e.reshape(T * e.top_k, e.n_experts),
+                         axis=0) - 1
+        pos = pos.reshape(T, e.top_k, e.n_experts)
+        pos_tk = jnp.sum(pos * onehot_e, axis=-1)              # (T, k)
+        within = (pos_tk < capacity)[..., None]                # (T, k, 1)
+        pos_onehot = jax.nn.one_hot(pos_tk, capacity, dtype=jnp.float32)
+        disp = jnp.einsum("tke,tkc->tec", onehot_e * within, pos_onehot)
+        xb = jnp.einsum("tec,td->ecd", disp,
+                        xf.astype(jnp.float32)).astype(x.dtype)
     yb = _expert_ffn(p["gate"], p["up"], p["down"], xb)
-    out = jnp.einsum("tec,ecd->td", comb, yb.astype(jnp.float32))
-    out = out.reshape(B, S, d).astype(x.dtype)
+    with jax.named_scope("combine"):
+        comb = jnp.einsum("tke,tkc,tk->tec", onehot_e * within, pos_onehot, w)
+        out = jnp.einsum("tec,ecd->td", comb, yb.astype(jnp.float32))
+        out = out.reshape(B, S, d).astype(x.dtype)
     if "shared" in p:
         from repro.models.layers import mlp_apply
         out = out + mlp_apply(p["shared"], x, "swiglu")

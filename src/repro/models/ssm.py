@@ -115,6 +115,7 @@ def _ssm_coeffs1(p, xz, cfg):
     return x, z, dt, Bm, Cm, A
 
 
+@jax.named_scope("ssm")
 def mamba1_forward(p, x_seq, cfg: ModelConfig, impl="scan", state=None):
     """x_seq: (B, S, d_model) -> (out, final_state dict(conv, ssm)).
 
@@ -197,6 +198,7 @@ def mamba1_forward(p, x_seq, cfg: ModelConfig, impl="scan", state=None):
                                "conv": conv_tail.astype(jnp.bfloat16)}
 
 
+@jax.named_scope("ssm")
 def mamba1_decode(p, x_t, state, cfg: ModelConfig):
     """One-token decode.  x_t: (B, 1, d).  state: dict(conv, ssm)."""
     s = cfg.ssm
@@ -226,6 +228,7 @@ def mamba1_decode(p, x_t, state, cfg: ModelConfig):
 # mamba2 (SSD, chunked)
 
 
+@jax.named_scope("ssm")
 def mamba2_forward(p, x_seq, cfg: ModelConfig, state=None):
     """x_seq: (B, S, d_model) -> (out, final ssm state (B,H,P,N))."""
     s = cfg.ssm
@@ -288,6 +291,7 @@ def mamba2_forward(p, x_seq, cfg: ModelConfig, state=None):
         {"ssm": hT, "conv": conv_tail.astype(jnp.bfloat16)}
 
 
+@jax.named_scope("ssm")
 def mamba2_decode(p, x_t, state, cfg: ModelConfig):
     """One-token decode.  state: dict(conv (B,conv_dim,k-1), ssm (B,H,P,N))."""
     s = cfg.ssm

@@ -124,13 +124,16 @@ def _window_schedule(cfg: ModelConfig) -> jnp.ndarray:
     return jnp.full((L,), cfg.window, jnp.int32)
 
 
-def _rope_for(cfg: ModelConfig, positions):
+@jax.named_scope("attention")
+def _rope_for(cfg: ModelConfig, start, n: int):
+    """RoPE tables of positions ``start`` .. ``start + n - 1``."""
     if cfg.rope_theta <= 0:
         return None, None
     dim = cfg.mla.qk_rope_dim if cfg.mla is not None else cfg.resolved_head_dim
-    return rope_tables(positions, dim, cfg.rope_theta)
+    return rope_tables(start + jnp.arange(n), dim, cfg.rope_theta)
 
 
+@jax.named_scope("embed")
 def _embed_tokens(cfg: ModelConfig, p, tokens, pos_offset=0):
     x = p["embed"][tokens]
     if cfg.family == "encdec":
@@ -142,6 +145,7 @@ def _embed_tokens(cfg: ModelConfig, p, tokens, pos_offset=0):
     return x.astype(jnp.bfloat16)
 
 
+@jax.named_scope("logits")
 def _logits(cfg: ModelConfig, p, x):
     x = apply_norm(cfg.norm, x, p["final_norm"])
     if cfg.tie_embeddings:
@@ -149,6 +153,7 @@ def _logits(cfg: ModelConfig, p, x):
     return x @ p["lm_head"]
 
 
+@jax.named_scope("layers")
 def _encoder_forward(cfg: ModelConfig, p, frames):
     """frames: (B, n_ctx, d) precomputed (frontend stub).  Whisper encoder."""
     x = frames.astype(jnp.float32) \
@@ -172,9 +177,10 @@ def _encoder_forward(cfg: ModelConfig, p, frames):
 # backbone (full-sequence; train and prefill)
 
 
-def _backbone(cfg: ModelConfig, p, x, positions, xa=None, collect=False):
+@jax.named_scope("layers")
+def _backbone(cfg: ModelConfig, p, x, xa=None, collect=False):
     """Returns (x, aux(lb, rz), collected-states dict or None)."""
-    cos, sin = _rope_for(cfg, positions)
+    cos, sin = _rope_for(cfg, 0, x.shape[1])
 
     if cfg.family == "ssm":
         from repro.dist import context as dist_ctx
@@ -341,33 +347,36 @@ def _prepare_inputs(cfg: ModelConfig, params, batch):
     if cfg.family == "encdec":
         xa = _encoder_forward(cfg, params, batch["frames"])
     if cfg.family == "vlm":
-        x = jnp.concatenate([batch["patches"].astype(x.dtype), x], axis=1)
+        with jax.named_scope("embed"):
+            x = jnp.concatenate([batch["patches"].astype(x.dtype), x], axis=1)
     return x, xa
 
 
 def train_forward(cfg: ModelConfig, params, batch):
     x, xa = _prepare_inputs(cfg, params, batch)
-    positions = jnp.arange(x.shape[1])
-    x, (lb, rz), _ = _backbone(cfg, params, x, positions, xa=xa)
-    if cfg.family == "vlm":
-        x = x[:, cfg.n_patches:]
-    return _logits(cfg, params, x), {"load_balance": lb, "router_z": rz}
+    x, (lb, rz), _ = _backbone(cfg, params, x, xa=xa)
+    with jax.named_scope("logits"):     # of the text positions alone
+        if cfg.family == "vlm":
+            x = x[:, cfg.n_patches:]
+        logits = _logits(cfg, params, x)
+    return logits, {"load_balance": lb, "router_z": rz}
 
 
 def loss_fn(cfg: ModelConfig, params, batch):
     logits, aux = train_forward(cfg, params, batch)
-    labels = batch["labels"]
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    label_logit = jnp.take_along_axis(logits, labels[..., None],
-                                      axis=-1)[..., 0]
-    nll = jnp.mean(logz - label_logit)
-    zloss = 1e-4 * jnp.mean(logz ** 2)
-    moe_loss = jnp.zeros((), jnp.float32)
-    if cfg.moe is not None:
-        moe_loss = (cfg.moe.aux_loss_coef * aux["load_balance"]
-                    + cfg.moe.router_z_coef * aux["router_z"])
-    loss = nll + zloss + moe_loss
+    with jax.named_scope("loss"):
+        labels = batch["labels"]
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        label_logit = jnp.take_along_axis(logits, labels[..., None],
+                                          axis=-1)[..., 0]
+        nll = jnp.mean(logz - label_logit)
+        zloss = 1e-4 * jnp.mean(logz ** 2)
+        moe_loss = jnp.zeros((), jnp.float32)
+        if cfg.moe is not None:
+            moe_loss = (cfg.moe.aux_loss_coef * aux["load_balance"]
+                        + cfg.moe.router_z_coef * aux["router_z"])
+        loss = nll + zloss + moe_loss
     metrics = {"loss": loss, "nll": nll, "zloss": zloss, "moe_loss": moe_loss}
     return loss, metrics
 
@@ -440,11 +449,19 @@ def prefill_forward(cfg: ModelConfig, params, batch,
     prompt_len = S + (cfg.n_patches if cfg.family == "vlm" else 0)
     max_seq = max(max_seq or prompt_len, prompt_len)
     x, xa = _prepare_inputs(cfg, params, batch)
-    positions = jnp.arange(x.shape[1])
-    x, _, collected = _backbone(cfg, params, x, positions, xa=xa,
-                                collect=True)
-    cache, _ = init_cache(cfg, B, max_seq)
+    x, _, collected = _backbone(cfg, params, x, xa=xa, collect=True)
+    cache = _prefill_cache(cfg, collected, B, max_seq)
+    # note: a VLM's patch prefix occupies cache positions [0, n_patches)
+    with jax.named_scope("logits"):     # of the last position alone
+        logits = _logits(cfg, params, x[:, -1:])
+    return logits, cache
 
+
+@jax.named_scope("kv_cache")
+def _prefill_cache(cfg: ModelConfig, collected, B: int, max_seq: int):
+    """The cache of ``max_seq`` positions holding the states the prefill's
+    layer stack collected."""
+    cache, _ = init_cache(cfg, B, max_seq)
     if cfg.family == "ssm":
         cache["conv"] = collected["conv"].astype(cache["conv"].dtype)
         cache["ssm"] = collected["ssm"]
@@ -470,10 +487,7 @@ def prefill_forward(cfg: ModelConfig, params, batch,
         if cfg.family == "encdec":
             cache["xk"] = xkv[0].astype(cache["xk"].dtype)
             cache["xv"] = xkv[1].astype(cache["xv"].dtype)
-    if cfg.family == "vlm":
-        pass  # note: patch prefix occupies cache positions [0, n_patches)
-    logits = _logits(cfg, params, x[:, -1:])
-    return logits, cache
+    return cache
 
 
 def _fill_kv(cache_kv, new):
@@ -489,8 +503,14 @@ def decode_forward(cfg: ModelConfig, params, cache, tokens, pos):
     """One decode step.  tokens: (B, 1); pos: scalar position (traced ok).
     Returns (logits (B, 1, V), new cache)."""
     x = _embed_tokens_decode(cfg, params, tokens, pos)
-    positions = jnp.full((1,), pos)
-    cos, sin = _rope_for(cfg, positions)
+    x, cache = _decode_layers(cfg, params, cache, x, pos)
+    return _logits(cfg, params, x), cache
+
+
+@jax.named_scope("layers")
+def _decode_layers(cfg: ModelConfig, params, cache, x, pos):
+    """The layer stack of one decode step: (x, new cache)."""
+    cos, sin = _rope_for(cfg, pos, 1)
 
     if cfg.family == "ssm":
         dec = (ssm_mod.mamba1_decode if cfg.ssm.version == 1
@@ -503,7 +523,7 @@ def decode_forward(cfg: ModelConfig, params, cache, tokens, pos):
             return x + h, (new["conv"], new["ssm"])
         x, (conv, st) = jax.lax.scan(
             body, x, (params["layers"], cache["conv"], cache["ssm"]))
-        return _logits(cfg, params, x), dict(cache, conv=conv, ssm=st)
+        return x, dict(cache, conv=conv, ssm=st)
 
     if cfg.family == "hybrid":
         k = cfg.hybrid_attn_every
@@ -537,7 +557,7 @@ def decode_forward(cfg: ModelConfig, params, cache, tokens, pos):
             superblock, x, (pls, conv, st, cache["k"], cache["v"]))
         cache = dict(cache, conv=conv.reshape(cache["conv"].shape),
                      ssm=st.reshape(cache["ssm"].shape), k=ck, v=cv)
-        return _logits(cfg, params, x), cache
+        return x, cache
 
     windows = _window_schedule(cfg)
 
@@ -566,8 +586,7 @@ def decode_forward(cfg: ModelConfig, params, cache, tokens, pos):
             x = x + h
             cks.append(ck)
             cvs.append(cv)
-        cache = dict(cache, k=jnp.stack(cks), v=jnp.stack(cvs))
-        return _logits(cfg, params, x), cache
+        return x, dict(cache, k=jnp.stack(cks), v=jnp.stack(cvs))
 
     if cfg.mla is not None:
         def body(x, xs):
@@ -585,7 +604,7 @@ def decode_forward(cfg: ModelConfig, params, cache, tokens, pos):
         x, (ckv, krope) = jax.lax.scan(
             body, x, (params["layers"], cache["ckv"], cache["krope"],
                       windows))
-        return _logits(cfg, params, x), dict(cache, ckv=ckv, krope=krope)
+        return x, dict(cache, ckv=ckv, krope=krope)
 
     is_encdec = cfg.family == "encdec"
 
@@ -616,9 +635,10 @@ def decode_forward(cfg: ModelConfig, params, cache, tokens, pos):
     else:
         xs = (params["layers"], cache["k"], cache["v"], windows)
     x, (ck, cv) = jax.lax.scan(body, x, xs)
-    return _logits(cfg, params, x), dict(cache, k=ck, v=cv)
+    return x, dict(cache, k=ck, v=cv)
 
 
+@jax.named_scope("embed")
 def _embed_tokens_decode(cfg: ModelConfig, p, tokens, pos):
     x = p["embed"][tokens]
     if cfg.family == "encdec":

@@ -26,6 +26,7 @@ def make_prefill_step(cfg: ModelConfig, max_seq: int):
 def make_decode_step(cfg: ModelConfig, *, greedy: bool = True):
     def decode_step(params, cache, tokens, pos):
         logits, cache = T.decode_forward(cfg, params, cache, tokens, pos)
-        next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return next_tok[:, None], cache
     return decode_step

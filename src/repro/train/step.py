@@ -66,11 +66,13 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig):
                                              metrics)
         else:
             loss, metrics, grads = grads_of(params, batch)
-        grads, gnorm = clip_by_global_norm(grads, tc.grad_clip)
-        params, opt_state = adamw_update(
-            grads, opt_state, params, lr=lr_fn(step),
-            weight_decay=tc.weight_decay)
-        metrics = dict(metrics, grad_norm=gnorm, lr=lr_fn(step))
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, tc.grad_clip)
+            lr = lr_fn(step)
+            params, opt_state = adamw_update(
+                grads, opt_state, params, lr=lr,
+                weight_decay=tc.weight_decay)
+        metrics = dict(metrics, grad_norm=gnorm, lr=lr)
         return params, opt_state, metrics
 
     return train_step

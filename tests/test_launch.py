@@ -49,6 +49,37 @@ def test_serve_pads_the_last_batch_and_keeps_its_cache():
         serve(cfg, params, prompts, batch=2, max_new=2)
 
 
+def test_serve_marks_each_batch_phase_with_a_span(tmp_path):
+    """Four profiler spans a batch, in order and apart, on one host line,
+    each carrying its batch's index: what the benchmark's per-layer serve
+    metrics read off a chip trace."""
+    import glob
+
+    from jax.profiler import ProfileData
+    cfg = get_smoke_config("granite_moe_1b_a400m")
+    params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
+    prompts = list(np.random.default_rng(0).integers(0, cfg.vocab, (5, 8),
+                                                     dtype=np.int32))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        serve(cfg, params, prompts, batch=2, max_new=3, emit=lambda _: None)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [((plane.name, line.name), e.name, dict(e.stats)["batch"],
+              e.start_ns, e.start_ns + e.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve/")]
+    assert len({where for where, *_ in spans}) == 1
+    phases = ["serve/admit", "serve/prefill", "serve/decode", "serve/collect"]
+    for b in range(3):
+        mine = sorted((s for s in spans if s[2] == b), key=lambda s: s[3])
+        assert [s[1] for s in mine] == phases
+        assert all(a[4] <= z[3] for a, z in zip(mine, mine[1:]))
+    assert len(spans) == 3 * len(phases)
+
+
 def test_require_tpu_refuses_the_cpu():
     if jax.devices()[0].platform == "tpu":
         pytest.skip("checks the refusal on a host with no TPU")
