@@ -607,15 +607,25 @@ def _decode_layers(cfg: ModelConfig, params, cache, x, pos):
         return x, dict(cache, ckv=ckv, krope=krope)
 
     is_encdec = cfg.family == "encdec"
+    cache_k, cache_v = cache["k"], cache["v"]
 
+    # Each layer reads its slice of the closed-over cache and emits only
+    # the new position's K and V; one write after the scan puts all L of
+    # them into the cache.  Scanning the cache as xs would return it as a
+    # stacked output, written whole and copied back every step.
     def body(x, xs):
         if is_encdec:
-            pl, ck, cv, window, xk, xv = xs
+            pl, li, window, xk, xv = xs
         else:
-            pl, ck, cv, window = xs
+            pl, li, window = xs
         h, ck, cv = attn.gqa_decode(
             pl["attn"], apply_norm(cfg.norm, x, pl["norm1"]),
-            ck, cv, cos, sin, cfg=cfg, pos=pos, window=window)
+            jax.lax.dynamic_index_in_dim(cache_k, li, keepdims=False),
+            jax.lax.dynamic_index_in_dim(cache_v, li, keepdims=False),
+            cos, sin, cfg=cfg, pos=pos, window=window)
+        with jax.named_scope("kv_cache"):
+            new_kv = (jax.lax.dynamic_slice_in_dim(ck, pos, 1, 2),
+                      jax.lax.dynamic_slice_in_dim(cv, pos, 1, 2))
         x = x + h
         if is_encdec:
             h, _, _ = attn.gqa_decode(
@@ -627,15 +637,17 @@ def _decode_layers(cfg: ModelConfig, params, cache, x, pos):
             h, _ = moe_mod.moe_apply(pl["moe"], h_in, cfg)
         else:
             h = mlp_apply(pl["mlp"], h_in, cfg.activation)
-        return x + h, (ck, cv)
+        return x + h, new_kv
 
+    xs = (params["layers"], jnp.arange(cfg.n_layers), windows)
     if is_encdec:
-        xs = (params["layers"], cache["k"], cache["v"], windows,
-              cache["xk"], cache["xv"])
-    else:
-        xs = (params["layers"], cache["k"], cache["v"], windows)
-    x, (ck, cv) = jax.lax.scan(body, x, xs)
-    return x, dict(cache, k=ck, v=cv)
+        xs += (cache["xk"], cache["xv"])
+    x, (k_new, v_new) = jax.lax.scan(body, x, xs)
+    with jax.named_scope("kv_cache"):
+        at = (0, 0, 0, pos, 0)
+        cache_k = jax.lax.dynamic_update_slice(cache_k, k_new, at)
+        cache_v = jax.lax.dynamic_update_slice(cache_v, v_new, at)
+    return x, dict(cache, k=cache_k, v=cache_v)
 
 
 @jax.named_scope("embed")

@@ -1,0 +1,83 @@
+"""What one decode step does to the KV cache: it writes the new position's
+K and V in every layer, what a prefill of one more token writes there, and
+leaves every other position bit for bit as it was.  A second step then
+reads the first step's write as a prefill of the longer prompt would.
+Run at smoke size on the configurations the decode layer scan serves: a
+sparse GQA model, a dense multi-head one and an encoder-decoder, whose
+cross-attention cache a decode step only reads.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.configs import get_smoke_config
+from repro.models import transformer as T
+
+B, S = 2, 8
+MAX_SEQ = S + 4
+TOL = dict(rtol=0.08, atol=0.15)   # test_decode_matches_forward's decode
+
+
+def _batch(cfg, tokens):
+    batch = {"tokens": tokens}
+    if cfg.family == "encdec":
+        batch["frames"] = jnp.full((B, cfg.encoder.n_ctx, cfg.d_model), .1,
+                                   jnp.float32)
+    return batch
+
+
+@pytest.fixture(scope="module", params=["granite_moe_1b_a400m",
+                                        "phi3_mini_3_8b", "whisper_small"])
+def stepped(request):
+    """A prefill of S tokens, then one decode step at position S."""
+    cfg = get_smoke_config(request.param)
+    if cfg.moe is not None:  # no capacity drops between prompt lengths
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
+    params, _ = T.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(1)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab, (B, S + 2)), jnp.int32)
+
+    def prefill(n):
+        return T.prefill_forward(cfg, params, _batch(cfg, tokens[:, :n]),
+                                 max_seq=MAX_SEQ)
+    _, before = prefill(S)
+    _, after = T.decode_forward(cfg, params, before, tokens[:, S:S + 1], S)
+    return dict(cfg=cfg, params=params, tokens=tokens, prefill=prefill,
+                before=before, after=after)
+
+
+def _bits(a):
+    return np.asarray(a).view(np.uint16)
+
+
+def test_decode_leaves_every_other_position_bit_for_bit(stepped):
+    before, after = stepped["before"], stepped["after"]
+    assert set(after) == set(before)
+    for name in before:
+        old, new = _bits(before[name]), _bits(after[name])
+        assert new.shape == old.shape, name
+        if name in ("k", "v"):        # (L, B, Hkv, S, hd): all but pos
+            old, new = np.delete(old, S, axis=3), np.delete(new, S, axis=3)
+        np.testing.assert_array_equal(new, old, err_msg=name)
+
+
+def test_decode_writes_what_prefill_writes_at_pos(stepped):
+    _, longer = stepped["prefill"](S + 1)
+    for name in ("k", "v"):
+        got = np.asarray(stepped["after"][name][:, :, :, S], np.float32)
+        want = np.asarray(longer[name][:, :, :, S], np.float32)
+        assert np.abs(got).max() > 0, name
+        np.testing.assert_allclose(got, want, err_msg=name, **TOL)
+
+
+def test_second_step_matches_prefill(stepped):
+    cfg, tokens = stepped["cfg"], stepped["tokens"]
+    logits, _ = T.decode_forward(cfg, stepped["params"], stepped["after"],
+                                 tokens[:, S + 1:S + 2], S + 1)
+    want, _ = stepped["prefill"](S + 2)
+    np.testing.assert_allclose(np.asarray(logits[:, 0], np.float32),
+                               np.asarray(want[:, 0], np.float32), **TOL)

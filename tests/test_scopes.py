@@ -93,6 +93,8 @@ def test_every_operation_sits_under_a_documented_scope(arch, step):
     assert instructions
     unscoped, dots, cache_writes, seen = [], 0, 0, set()
     one_layer_cache = (B, cfg.n_kv_heads, S + NEW, cfg.resolved_head_dim)
+    whole_cache = (cfg.n_layers,) + one_layer_cache
+    whole_cache_writes = 0
     arguments = {name for _, opcode, _, name in instructions
                  if opcode == "parameter"}
     for comp, opcode, dims, op_name in instructions:
@@ -114,10 +116,14 @@ def test_every_operation_sits_under_a_documented_scope(arch, step):
         if opcode == "dynamic-update-slice" and dims == one_layer_cache:
             cache_writes += 1
             assert "kv_cache" in found, op_name
+        if opcode == "dynamic-update-slice" and dims == whole_cache:
+            whole_cache_writes += 1
+            assert "kv_cache" in found, op_name
     assert not unscoped, unscoped
     assert dots > 0
     if step == "decode":
         assert cache_writes > 0       # the new token's K and V
+        assert whole_cache_writes > 0  # ... and all layers' into the cache
         assert {"kv_cache", "sample"} <= seen
     if step == "train":
         assert {"loss", "optimizer"} <= seen

@@ -1,17 +1,19 @@
 """Compiles for a described TPU v5e, with no chip attached: the Pallas
-kernels at the widths ``chip_smoke.py`` runs, and granite's full-width
-decode step.  The TPU compiler refuses here what interpret mode accepts
-(a slice the tiling cannot prove aligned, more VMEM than a kernel may
-use) and a program that does not fit the chip's 16 GB of HBM.
+kernels at the widths ``chip_smoke.py`` runs, and the full-width decode
+steps of granite and Phi-3.  The TPU compiler refuses here what interpret
+mode accepts (a slice the tiling cannot prove aligned, more VMEM than a
+kernel may use) and a program that does not fit the chip's 16 GB of HBM.
 
 The topology is described inside a fixture, never while a module is
 imported: one process at a time may load the TPU library, so only the
 worker that runs this file does.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -66,10 +68,8 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_granite_decode_compiles_for_v5e(one_chip):
-    cfg = GRANITE
-    max_seq = PROMPT_LEN + MAX_NEW
-
+def _compile_decode(one_chip, cfg, batch, max_seq):
+    """The donated decode step at full width, compiled for one v5e."""
     def on_chip(tree):
         return jax.tree_util.tree_map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
@@ -77,10 +77,35 @@ def test_granite_decode_compiles_for_v5e(one_chip):
     params = on_chip(jax.eval_shape(
         lambda k: T.init_params(cfg, k)[0], jax.random.PRNGKey(0)))
     cache = on_chip(jax.eval_shape(
-        lambda: T.init_cache(cfg, BATCH, max_seq)[0]))
-    tokens = jax.ShapeDtypeStruct((BATCH, 1), jnp.int32, sharding=one_chip)
+        lambda: T.init_cache(cfg, batch, max_seq)[0]))
+    tokens = jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(make_decode_step(cfg), donate_argnums=(1,)).lower(
+    return jax.jit(make_decode_step(cfg), donate_argnums=(1,)).lower(
         params, cache, tokens, pos).compile()
+
+
+def test_granite_decode_compiles_for_v5e(one_chip):
+    compiled = _compile_decode(one_chip, GRANITE, BATCH,
+                               PROMPT_LEN + MAX_NEW)
     total = program_bytes(compiled.memory_analysis())
     assert total < HBM_BYTES, total
+
+
+@pytest.mark.parametrize("arch,batch", [("granite_moe_1b_a400m", 32),
+                                        ("phi3_mini_3_8b", 4)])
+def test_decode_writes_the_cache_in_place_on_v5e(one_chip, arch, batch):
+    """At the chat cells' shapes the layer scan neither stacks the cache as
+    its output nor copies it back: the step's temporaries stay under one
+    K cache, and no copy has the whole cache's shape."""
+    cfg, max_seq = get_config(arch), 1280
+    compiled = _compile_decode(one_chip, cfg, batch, max_seq)
+    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_seq,
+             cfg.resolved_head_dim)
+    one_cache = 2 * int(np.prod(shape))                   # bf16
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < one_cache, (temp, one_cache)
+    dims = ",".join(map(str, shape))
+    whole = re.compile(r"= bf16\[%s\]\S* copy\(" % dims)
+    copies = [line.strip() for line in compiled.as_text().splitlines()
+              if whole.search(line)]
+    assert not copies, copies
