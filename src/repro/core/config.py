@@ -23,6 +23,10 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_z_coef: float = 1e-3
     aux_loss_coef: float = 1e-2
+    # top-k weights renormalised to sum to one; else the softmax
+    # probabilities times ``routed_scaling_factor`` (DeepSeek-V2)
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -33,6 +37,18 @@ class MLAConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it
+    (``rope_scaling`` with ``type: yarn``)."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -69,7 +85,11 @@ class ModelConfig:
     norm: str = "rmsnorm"
     tie_embeddings: bool = False
     rope_theta: float = 10_000.0
+    yarn: Optional[YaRNConfig] = None
     max_seq: int = 131_072
+    # leading layers with a dense MLP of width d_ff ahead of the MoE stack
+    # (DeepSeek's first_k_dense_replace)
+    n_dense_layers: int = 0
     # attention pattern
     window: int = 0             # sliding window size (0 = full)
     local_global_ratio: int = 0 # e.g. 5 => 5 local : 1 global (gemma3)
@@ -146,7 +166,9 @@ class ModelConfig:
             n += L * (mamba + d)
             n += attn + mlp + 2 * d  # shared block, counted once
             return n
-        n += L * (attn + mlp + 2 * d)
+        nd = self.n_dense_layers
+        n += L * (attn + 2 * d) + (L - nd) * mlp \
+            + nd * (gates + 1) * d * self.d_ff
         if self.encoder is not None:
             # encoder layers: self-attn + mlp ; decoder adds cross-attn
             n += self.encoder.n_layers * (attn + mlp + 2 * d)
@@ -161,7 +183,8 @@ class ModelConfig:
         gates = 2 if self.activation in ("swiglu", "geglu") else 1
         full_mlp = (e.n_experts + e.n_shared) * (gates + 1) * self.d_model * e.d_ff_expert
         act_mlp = (e.top_k + e.n_shared) * (gates + 1) * self.d_model * e.d_ff_expert
-        return self.param_count() - self.n_layers * (full_mlp - act_mlp)
+        return self.param_count() - (self.n_layers - self.n_dense_layers) \
+            * (full_mlp - act_mlp)
 
     def to_json(self) -> str:
         return json.dumps(dataclasses.asdict(self), indent=2)

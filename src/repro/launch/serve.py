@@ -16,6 +16,8 @@ reduced preset of the same family, which runs on any device.
 from __future__ import annotations
 
 import argparse
+import contextlib
+import functools
 import time
 from dataclasses import dataclass
 from typing import Any, Sequence
@@ -24,9 +26,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs import get_config, get_smoke_config
-from repro.core.config import ModelConfig
+from repro.core.config import ModelConfig, ShapeConfig
+from repro.dist import context as dist_ctx
+from repro.dist import sharding as shd
 from repro.launch.chip import (CompileTimer, program_bytes, require_tpu,
                                use_compile_cache)
 from repro.models import transformer as T
@@ -58,8 +63,40 @@ def model_inputs(cfg: ModelConfig, tokens) -> dict:
     return batch
 
 
+@contextlib.contextmanager
+def _installed(mesh, rules):
+    """``mesh`` and its sharding ``rules`` active for the model code."""
+    before = dist_ctx.get_mesh(), shd.active_rules()
+    dist_ctx.set_mesh(mesh)
+    shd.set_active_rules(rules)
+    try:
+        yield
+    finally:
+        dist_ctx.set_mesh(before[0])
+        shd.set_active_rules(before[1])
+
+
+def _on_mesh(cfg: ModelConfig, mesh, batch: int, max_seq: int):
+    """The sharding rules of this serving shape on ``mesh``, and the
+    shardings of the params, of the cache and of a batch's rows, from
+    their ``Leaf`` axes by ``rules_for``."""
+    rules = shd.rules_for(cfg, ShapeConfig("serve", seq_len=max_seq,
+                                           global_batch=batch,
+                                           kind="decode"), mesh)
+    axes = {}
+
+    def shapes():
+        params, axes["params"] = T.init_params(cfg, jax.random.key(0))
+        cache, axes["cache"] = T.init_cache(cfg, batch, max_seq)
+        return params, cache
+    params, cache = jax.eval_shape(shapes)
+    rows = NamedSharding(mesh, rules.spec_for(("batch",), (batch,)))
+    return (rules, rules.tree_shardings(axes["params"], params),
+            rules.tree_shardings(axes["cache"], cache), rows)
+
+
 def serve(cfg: ModelConfig, params, prompts: Sequence[np.ndarray], *,
-          batch: int, max_new: int, emit=print) -> ServeResult:
+          batch: int, max_new: int, emit=print, mesh=None) -> ServeResult:
     """Generate ``max_new`` greedy tokens for each prompt, in static
     batches of ``batch``.  All prompts have one length.
 
@@ -73,57 +110,82 @@ def serve(cfg: ModelConfig, params, prompts: Sequence[np.ndarray], *,
     ``serve/decode`` (the decode steps, up to the last token's being
     ready) and ``serve/collect`` (the tokens to the host).  On the first
     batch, prefill and decode also trace and compile their steps.
-    ``emit`` runs between batches, outside the spans."""
+    ``emit`` runs between batches, outside the spans.
+
+    With a ``mesh``, the mesh and its ``rules_for`` rules are installed
+    for the call; params, cache and each batch's rows are placed by their
+    logical axes, and the MoE layers take their expert-parallel path over
+    the mesh's ``model`` axis."""
     if max_new < 3:
         raise ValueError(f"max_new={max_new}: needs 3 or more, since two "
                          "tokens come from untimed steps")
     prompt_len = len(prompts[0])
     pos0 = prompt_len + (cfg.n_patches if cfg.family == "vlm" else 0)
-    prefill = jax.jit(make_prefill_step(cfg, max_seq=pos0 + max_new))
-    decode = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
+    step = make_prefill_step(cfg, max_seq=pos0 + max_new)
+    if mesh is None:
+        prefill = jax.jit(step)
+        decode = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
+        place, installed = (lambda x: x), contextlib.nullcontext()
+    else:
+        rules, params_sh, cache_sh, rows_sh = _on_mesh(cfg, mesh, batch,
+                                                       pos0 + max_new)
+        one = NamedSharding(mesh, PartitionSpec())
+        prefill = jax.jit(step, in_shardings=(params_sh, rows_sh),
+                          out_shardings=(one, cache_sh))
+        decode = jax.jit(make_decode_step(cfg), donate_argnums=(1,),
+                         in_shardings=(params_sh, cache_sh, rows_sh, one),
+                         out_shardings=(rows_sh, cache_sh))
+        params = jax.device_put(params, params_sh)
+        place = functools.partial(jax.device_put, device=rows_sh)
+        installed = _installed(mesh, rules)
     queue = list(prompts)
     outs = []
     decode_s = 0.0
-    with CompileTimer() as timer:
-        while queue:
-            b = len(outs)
-            with TraceAnnotation("serve/admit", batch=b):
-                rows = queue[:batch]
-                queue = queue[batch:]
-                padded = rows + [rows[-1]] * (batch - len(rows))
-                inputs = model_inputs(cfg, jnp.asarray(np.stack(padded)))
-            with TraceAnnotation("serve/prefill", batch=b):
-                logits, cache = prefill(params, inputs)
-                tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-                toks = [tok]
-            with TraceAnnotation("serve/decode", batch=b):
-                tok, cache = decode(params, cache, tok, jnp.int32(pos0))
-                toks.append(tok)
-                tok.block_until_ready()
-                t0 = time.perf_counter()
-                for i in range(2, max_new):
-                    tok, cache = decode(params, cache, tok,
-                                        jnp.int32(pos0 + i - 1))
+    with installed:
+        with CompileTimer() as timer:
+            while queue:
+                b = len(outs)
+                with TraceAnnotation("serve/admit", batch=b):
+                    rows = queue[:batch]
+                    queue = queue[batch:]
+                    padded = rows + [rows[-1]] * (batch - len(rows))
+                    inputs = place(model_inputs(
+                        cfg, jnp.asarray(np.stack(padded))))
+                with TraceAnnotation("serve/prefill", batch=b):
+                    logits, cache = prefill(params, inputs)
+                    tok = place(jnp.argmax(logits[:, -1], -1).astype(
+                        jnp.int32)[:, None])
+                    toks = [tok]
+                with TraceAnnotation("serve/decode", batch=b):
+                    tok, cache = decode(params, cache, tok, jnp.int32(pos0))
                     toks.append(tok)
-                tok.block_until_ready()
-                decode_s += time.perf_counter() - t0
-            with TraceAnnotation("serve/collect", batch=b):
-                out = np.asarray(jnp.concatenate(toks, 1))[:len(rows)]
-                outs.append(out)
-            emit(f"[batch] finished {len(rows)} requests "
-                 f"({sum(len(o) for o in outs)}/{len(prompts)}); sample "
-                 f"continuation: {out[0][:8]}")
-    # lowering again at the same shapes reuses the executables compiled
-    # above: this reads their memory, it compiles nothing
-    lowered = {"prefill": prefill.lower(params, inputs),
-               "decode": decode.lower(params, cache, tok, jnp.int32(pos0))}
+                    tok.block_until_ready()
+                    t0 = time.perf_counter()
+                    for i in range(2, max_new):
+                        tok, cache = decode(params, cache, tok,
+                                            jnp.int32(pos0 + i - 1))
+                        toks.append(tok)
+                    tok.block_until_ready()
+                    decode_s += time.perf_counter() - t0
+                with TraceAnnotation("serve/collect", batch=b):
+                    out = np.asarray(jnp.concatenate(toks, 1))[:len(rows)]
+                    outs.append(out)
+                emit(f"[batch] finished {len(rows)} requests "
+                     f"({sum(len(o) for o in outs)}/{len(prompts)}); sample "
+                     f"continuation: {out[0][:8]}")
+        # lowering again at the same shapes reuses the executables compiled
+        # above: this reads their memory, it compiles nothing
+        lowered = {"prefill": prefill.lower(params, inputs),
+                   "decode": decode.lower(params, cache, tok,
+                                          jnp.int32(pos0))}
+        memory = {k: low.compile().memory_analysis()
+                  for k, low in lowered.items()}
     n_batches = len(outs)
     return ServeResult(tokens=np.concatenate(outs), cache=cache,
                        next_pos=pos0 + max_new - 1,
                        compile_s=timer.seconds, decode_s=decode_s,
                        n_decode_steps=n_batches * (max_new - 2),
-                       memory={k: low.compile().memory_analysis()
-                               for k, low in lowered.items()})
+                       memory=memory)
 
 
 def main():

@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.config import MLAConfig, ModelConfig
-from repro.models.layers import Leaf, dense_init, norm_init, rmsnorm
+from repro.models.layers import (Leaf, dense_init, norm_init, rmsnorm,
+                                 yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -53,16 +54,16 @@ def attn_init(rng, cfg: ModelConfig, dtype=jnp.bfloat16):
 
 
 def chunked_attention(q, k, v, *, causal=True, window=0, q_offset=0,
-                      kv_valid=None, chunk=512):
+                      kv_valid=None, chunk=512, scale=None):
     """q: (B, H, Sq, D); k, v: (B, Hkv, Skv, D).  Returns (B, H, Sq, D).
 
     Scans over KV chunks with an online-softmax carry so live memory is
-    O(Sq * chunk) rather than O(Sq * Skv).
+    O(Sq * chunk) rather than O(Sq * Skv).  ``scale`` defaults to D ** -0.5.
     """
     B, H, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
     G = H // Hkv
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     chunk = min(chunk, Skv)
     if Skv % chunk:  # pad KV to a chunk multiple; padded keys are masked out
         pad = chunk - Skv % chunk
@@ -296,6 +297,17 @@ def _rope_heads(x, cos, sin):
 # MLA (DeepSeek V2) — compressed KV cache
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope) ** -0.5, times YaRN's mscale squared where the
+    config scales its rope (DeepseekV2Attention.softmax_scale)."""
+    m = cfg.mla
+    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
+    y = cfg.yarn
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 @jax.named_scope("attention")
 def mla_forward(p, x, cos, sin, *, cfg: ModelConfig, q_offset=0):
     """Train/prefill MLA, naive (expanded) form.  Returns (out, (c_kv, k_rope))."""
@@ -319,7 +331,8 @@ def mla_forward(p, x, cos, sin, *, cfg: ModelConfig, q_offset=0):
     # pad v head dim to qk dim for the shared kernel, then slice back
     out = chunked_attention(qf, k, jnp.pad(v, ((0, 0), (0, 0), (0, 0),
                                                (0, dn + dr - dv))),
-                            causal=True, q_offset=q_offset)[..., :dv]
+                            causal=True, q_offset=q_offset,
+                            scale=mla_softmax_scale(cfg))[..., :dv]
     out = out.transpose(0, 2, 1, 3).reshape(B, S, H * dv)
     return out @ p["o"], (c_kv, k_rope)
 
@@ -332,7 +345,7 @@ def mla_decode(p, x, cache_ckv, cache_krope, cos, sin, *, cfg: ModelConfig, pos)
     B = x.shape[0]
     H = cfg.n_heads
     dn, dr, dv, R = m.qk_nope_dim, m.qk_rope_dim, m.v_head_dim, m.kv_lora_rank
-    scale = (dn + dr) ** -0.5
+    scale = mla_softmax_scale(cfg)
     q = (x @ p["q"]).reshape(B, 1, H, dn + dr).transpose(0, 2, 1, 3)
     q_nope, q_rope = q[..., :dn], _rope_heads(q[..., dn:], cos, sin)
     kv = x @ p["kv_a"]

@@ -8,6 +8,7 @@ separates a Leaf-tree into (params, axes) trees.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Optional, Tuple
 
@@ -67,11 +68,44 @@ def apply_norm(kind, x, scale):
     return rmsnorm(x, scale) if kind == "rmsnorm" else layernorm(x, scale)
 
 
-def rope_tables(positions, dim, theta):
-    """positions: (...,) int32 -> cos/sin of shape positions.shape + (dim/2,)."""
-    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def yarn_mscale(factor, mscale):
+    """YaRN's attention temperature term (DeepSeek-V2's yarn_get_mscale)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim, theta, yarn):
+    """YaRN's rotary frequencies (DeepseekV2YarnRotaryEmbedding): the
+    high-frequency pairs keep theta's, the low-frequency ones are divided
+    by ``factor``, with a linear ramp between the correction dims."""
+    def corr_dim(rotations):
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    i = np.arange(dim // 2, dtype=np.float64)
+    keep = 1.0 - np.clip((i - low) / (high - low), 0.0, 1.0)
+    base = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    return base / yarn.factor * (1.0 - keep) + base * keep
+
+
+def rope_tables(positions, dim, theta, yarn=None):
+    """positions: (...,) int32 -> cos/sin of shape positions.shape + (dim/2,).
+    ``yarn``: a ``YaRNConfig`` for YaRN's frequencies and table scale."""
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    else:
+        inv = jnp.asarray(yarn_inv_freq(dim, theta, yarn), jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        scale = (yarn_mscale(yarn.factor, yarn.mscale)
+                 / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if scale != 1.0:
+            cos, sin = cos * scale, sin * scale
+    return cos, sin
 
 
 def apply_rope(x, cos, sin):

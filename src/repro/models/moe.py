@@ -22,7 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.config import ModelConfig
+from repro.core.config import ModelConfig, MoEConfig
 from repro.dist import context as dist_ctx
 from repro.models.layers import Leaf, dense_init
 
@@ -52,12 +52,18 @@ def moe_init(rng, cfg: ModelConfig, dtype=jnp.bfloat16):
 
 
 @jax.named_scope("route")
-def _route(x32, router_w, n_experts, top_k):
-    """Returns (weights (T,k) f32, experts (T,k) i32, aux dict)."""
+def _route(x32, router_w, e: MoEConfig):
+    """Returns (weights (T,k) f32, experts (T,k) i32, aux dict).  The top-k
+    softmax probabilities are renormalised to sum to one, or, where the
+    config says not (DeepSeek-V2), scaled by ``routed_scaling_factor``."""
+    n_experts = e.n_experts
     logits = x32 @ router_w                                # (T, E) f32
     probs = jax.nn.softmax(logits, axis=-1)
-    w, idx = jax.lax.top_k(probs, top_k)
-    w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+    w, idx = jax.lax.top_k(probs, e.top_k)
+    if e.norm_topk_prob:
+        w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+    elif e.routed_scaling_factor != 1.0:
+        w = w * e.routed_scaling_factor
     # load-balance aux loss (Switch-style) + router z-loss
     T = x32.shape[0]
     me = jnp.mean(probs, axis=0)
@@ -137,8 +143,7 @@ def _moe_local(p, x, cfg: ModelConfig, ep_rank, ep_size, psum_axis):
     e_start = ep_rank * e_local
     capacity = max(1, math.ceil(T * e.top_k * e.capacity_factor / e.n_experts))
 
-    w, idx, aux = _route(x.astype(jnp.float32), p["router"], e.n_experts,
-                         e.top_k)
+    w, idx, aux = _route(x.astype(jnp.float32), p["router"], e)
     xb, slot_of = _dispatch(x, idx, e.n_experts, e_start, e_local, capacity)
     with jax.named_scope("experts"):
         gate_l = jax.lax.dynamic_slice_in_dim(p["gate"], e_start, e_local, 0)
@@ -208,8 +213,7 @@ def _moe_local_shard(p, x, cfg, ep_rank, ep_size, psum_axis):
     e_local = e.n_experts // ep_size
     e_start = ep_rank * e_local
     capacity = max(1, math.ceil(T * e.top_k * e.capacity_factor / e.n_experts))
-    w, idx, aux = _route(x.astype(jnp.float32), p["router"], e.n_experts,
-                         e.top_k)
+    w, idx, aux = _route(x.astype(jnp.float32), p["router"], e)
     xb, slot_of = _dispatch(x, idx, e.n_experts, e_start, e_local, capacity)
     yb = _expert_ffn(p["gate"], p["up"], p["down"], xb)
     return _combine(yb, w, slot_of, psum_axis, x.dtype), aux
@@ -228,8 +232,7 @@ def moe_apply_einsum(p, x, cfg: ModelConfig):
     T = B * S
     xf = x.reshape(T, d)
     capacity = max(1, math.ceil(T * e.top_k * e.capacity_factor / e.n_experts))
-    w, idx, aux = _route(xf.astype(jnp.float32), p["router"], e.n_experts,
-                         e.top_k)
+    w, idx, aux = _route(xf.astype(jnp.float32), p["router"], e)
     # dispatch tensor (T, E, C)
     with jax.named_scope("dispatch"):
         onehot_e = jax.nn.one_hot(idx, e.n_experts,

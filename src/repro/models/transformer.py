@@ -87,7 +87,13 @@ def init_params(cfg: ModelConfig, rng) -> Tuple[Pytree, Pytree]:
     r = jax.random.split(rng, 6)
     kind = _layer_kind(cfg)
     p: Dict[str, Any] = {"embed": embed_init(r[0], cfg.vocab, cfg.d_model)}
-    p["layers"] = _stack_init(r[1], cfg, kind, cfg.n_layers)
+    nd = cfg.n_dense_layers
+    if nd:
+        r_dense, r_layers = jax.random.split(r[1])
+        p["dense_layers"] = _stack_init(r_dense, cfg, "attn_mlp", nd)
+        p["layers"] = _stack_init(r_layers, cfg, kind, cfg.n_layers - nd)
+    else:
+        p["layers"] = _stack_init(r[1], cfg, kind, cfg.n_layers)
     p["final_norm"] = norm_init(cfg.d_model)
     if not cfg.tie_embeddings:
         from repro.models.layers import dense_init
@@ -114,6 +120,22 @@ def init_params(cfg: ModelConfig, rng) -> Tuple[Pytree, Pytree]:
 ZERO_AUX = lambda: (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
 
 
+def _stacks(cfg: ModelConfig, p):
+    """The stacked layers in order, as (stack, first layer, layers): the
+    leading dense layers where the config has them, then ``layers``."""
+    nd = cfg.n_dense_layers
+    if not nd:
+        return [(p["layers"], 0, cfg.n_layers)]
+    return [(p["dense_layers"], 0, nd), (p["layers"], nd, cfg.n_layers - nd)]
+
+
+def _concat_layers(parts):
+    """Per-stack outputs stacked on the layer axis, joined in layer order."""
+    if len(parts) == 1:
+        return parts[0]
+    return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *parts)
+
+
 def _window_schedule(cfg: ModelConfig) -> jnp.ndarray:
     """Per-layer window sizes; 0 = full attention."""
     L = cfg.n_layers
@@ -130,7 +152,7 @@ def _rope_for(cfg: ModelConfig, start, n: int):
     if cfg.rope_theta <= 0:
         return None, None
     dim = cfg.mla.qk_rope_dim if cfg.mla is not None else cfg.resolved_head_dim
-    return rope_tables(start + jnp.arange(n), dim, cfg.rope_theta)
+    return rope_tables(start + jnp.arange(n), dim, cfg.rope_theta, cfg.yarn)
 
 
 @jax.named_scope("embed")
@@ -330,10 +352,15 @@ def _backbone(cfg: ModelConfig, p, x, xa=None, collect=False):
         return (x + h, lb, rz), ((kv, xkv) if collect else ())
 
     lb0, rz0 = ZERO_AUX()
-    (x, lb, rz), ys = jax.lax.scan(jax.checkpoint(body), (x, lb0, rz0),
-                                   (p["layers"], windows))
+    carry, ys = (x, lb0, rz0), []
+    for stack, first, n in _stacks(cfg, p):
+        w = windows if n == cfg.n_layers else windows[first:first + n]
+        carry, ys_stack = jax.lax.scan(jax.checkpoint(body), carry,
+                                       (stack, w))
+        ys.append(ys_stack)
+    x, lb, rz = carry
     L = cfg.n_layers
-    return x, (lb / L, rz / L), (ys if collect else None)
+    return x, (lb / L, rz / L), (_concat_layers(ys) if collect else None)
 
 
 # ---------------------------------------------------------------------------
@@ -588,29 +615,12 @@ def _decode_layers(cfg: ModelConfig, params, cache, x, pos):
             cvs.append(cv)
         return x, dict(cache, k=jnp.stack(cks), v=jnp.stack(cvs))
 
-    if cfg.mla is not None:
-        def body(x, xs):
-            pl, ckv, krope, _w = xs
-            h, ckv, krope = attn.mla_decode(
-                pl["attn"], apply_norm(cfg.norm, x, pl["norm1"]),
-                ckv, krope, cos, sin, cfg=cfg, pos=pos)
-            x = x + h
-            h_in = apply_norm(cfg.norm, x, pl["norm2"])
-            if "moe" in pl:
-                h, _ = moe_mod.moe_apply(pl["moe"], h_in, cfg)
-            else:
-                h = mlp_apply(pl["mlp"], h_in, cfg.activation)
-            return x + h, (ckv, krope)
-        x, (ckv, krope) = jax.lax.scan(
-            body, x, (params["layers"], cache["ckv"], cache["krope"],
-                      windows))
-        return x, dict(cache, ckv=ckv, krope=krope)
-
-    is_encdec = cfg.family == "encdec"
-    cache_k, cache_v = cache["k"], cache["v"]
+    is_encdec, mla = cfg.family == "encdec", cfg.mla is not None
+    names = ("ckv", "krope") if mla else ("k", "v")
+    full = tuple(cache[n] for n in names)
 
     # Each layer reads its slice of the closed-over cache and emits only
-    # the new position's K and V; one write after the scan puts all L of
+    # the new position's entries; one write after the scan puts all L of
     # them into the cache.  Scanning the cache as xs would return it as a
     # stacked output, written whole and copied back every step.
     def body(x, xs):
@@ -618,14 +628,18 @@ def _decode_layers(cfg: ModelConfig, params, cache, x, pos):
             pl, li, window, xk, xv = xs
         else:
             pl, li, window = xs
-        h, ck, cv = attn.gqa_decode(
-            pl["attn"], apply_norm(cfg.norm, x, pl["norm1"]),
-            jax.lax.dynamic_index_in_dim(cache_k, li, keepdims=False),
-            jax.lax.dynamic_index_in_dim(cache_v, li, keepdims=False),
-            cos, sin, cfg=cfg, pos=pos, window=window)
-        with jax.named_scope("kv_cache"):
-            new_kv = (jax.lax.dynamic_slice_in_dim(ck, pos, 1, 2),
-                      jax.lax.dynamic_slice_in_dim(cv, pos, 1, 2))
+        h_in = apply_norm(cfg.norm, x, pl["norm1"])
+        mine = [jax.lax.dynamic_index_in_dim(c, li, keepdims=False)
+                for c in full]
+        if mla:
+            h, *upd = attn.mla_decode(pl["attn"], h_in, *mine, cos, sin,
+                                      cfg=cfg, pos=pos)
+        else:
+            h, *upd = attn.gqa_decode(pl["attn"], h_in, *mine, cos, sin,
+                                      cfg=cfg, pos=pos, window=window)
+        with jax.named_scope("kv_cache"):   # the position axis: -2 of 3 or 4
+            new = tuple(jax.lax.dynamic_slice_in_dim(c, pos, 1, c.ndim - 2)
+                        for c in upd)
         x = x + h
         if is_encdec:
             h, _, _ = attn.gqa_decode(
@@ -637,17 +651,21 @@ def _decode_layers(cfg: ModelConfig, params, cache, x, pos):
             h, _ = moe_mod.moe_apply(pl["moe"], h_in, cfg)
         else:
             h = mlp_apply(pl["mlp"], h_in, cfg.activation)
-        return x + h, new_kv
+        return x + h, new
 
-    xs = (params["layers"], jnp.arange(cfg.n_layers), windows)
-    if is_encdec:
-        xs += (cache["xk"], cache["xv"])
-    x, (k_new, v_new) = jax.lax.scan(body, x, xs)
+    news = []
+    for stack, first, n in _stacks(cfg, params):
+        w = windows if n == cfg.n_layers else windows[first:first + n]
+        xs = (stack, jnp.arange(first, first + n), w)
+        if is_encdec:
+            xs += (cache["xk"], cache["xv"])
+        x, new = jax.lax.scan(body, x, xs)
+        news.append(new)
     with jax.named_scope("kv_cache"):
-        at = (0, 0, 0, pos, 0)
-        cache_k = jax.lax.dynamic_update_slice(cache_k, k_new, at)
-        cache_v = jax.lax.dynamic_update_slice(cache_v, v_new, at)
-    return x, dict(cache, k=cache_k, v=cache_v)
+        written = {name: jax.lax.dynamic_update_slice(
+                       c, new, (0,) * (c.ndim - 2) + (pos, 0))
+                   for name, c, new in zip(names, full, _concat_layers(news))}
+    return x, dict(cache, **written)
 
 
 @jax.named_scope("embed")

@@ -3,8 +3,9 @@ K and V in every layer, what a prefill of one more token writes there, and
 leaves every other position bit for bit as it was.  A second step then
 reads the first step's write as a prefill of the longer prompt would.
 Run at smoke size on the configurations the decode layer scan serves: a
-sparse GQA model, a dense multi-head one and an encoder-decoder, whose
-cross-attention cache a decode step only reads.
+sparse GQA model, a dense multi-head one, an encoder-decoder, whose
+cross-attention cache a decode step only reads, and DeepSeek-V2's latent
+cache (``ckv``, ``krope``: (L, B, S, ·)) across its dense and MoE stacks.
 """
 import dataclasses
 
@@ -19,6 +20,8 @@ from repro.models import transformer as T
 B, S = 2, 8
 MAX_SEQ = S + 4
 TOL = dict(rtol=0.08, atol=0.15)   # test_decode_matches_forward's decode
+# the caches a decode step writes at ``pos``, on their axis -2
+WRITTEN = ("k", "v", "ckv", "krope")
 
 
 def _batch(cfg, tokens):
@@ -30,7 +33,8 @@ def _batch(cfg, tokens):
 
 
 @pytest.fixture(scope="module", params=["granite_moe_1b_a400m",
-                                        "phi3_mini_3_8b", "whisper_small"])
+                                        "phi3_mini_3_8b", "whisper_small",
+                                        "deepseek_v2_lite_16b"])
 def stepped(request):
     """A prefill of S tokens, then one decode step at position S."""
     cfg = get_smoke_config(request.param)
@@ -60,16 +64,17 @@ def test_decode_leaves_every_other_position_bit_for_bit(stepped):
     for name in before:
         old, new = _bits(before[name]), _bits(after[name])
         assert new.shape == old.shape, name
-        if name in ("k", "v"):        # (L, B, Hkv, S, hd): all but pos
-            old, new = np.delete(old, S, axis=3), np.delete(new, S, axis=3)
+        if name in WRITTEN:           # all but pos
+            old = np.delete(old, S, axis=old.ndim - 2)
+            new = np.delete(new, S, axis=new.ndim - 2)
         np.testing.assert_array_equal(new, old, err_msg=name)
 
 
 def test_decode_writes_what_prefill_writes_at_pos(stepped):
     _, longer = stepped["prefill"](S + 1)
-    for name in ("k", "v"):
-        got = np.asarray(stepped["after"][name][:, :, :, S], np.float32)
-        want = np.asarray(longer[name][:, :, :, S], np.float32)
+    for name in set(WRITTEN) & set(longer):
+        got = np.take(np.asarray(stepped["after"][name], np.float32), S, -2)
+        want = np.take(np.asarray(longer[name], np.float32), S, -2)
         assert np.abs(got).max() > 0, name
         np.testing.assert_allclose(got, want, err_msg=name, **TOL)
 

@@ -131,3 +131,39 @@ def test_every_operation_sits_under_a_documented_scope(arch, step):
     moe = {"moe", "route", "dispatch", "experts", "combine"}
     assert (moe <= seen) == (cfg.moe is not None)
     assert ("mlp" in seen) == (cfg.moe is None)
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_every_deepseek_operation_sits_under_a_documented_scope(step):
+    """DeepSeek-V2's latent attention, its dense layer 0 and its MoE with
+    a shared expert: every instruction under a documented scope, and in
+    decode each layer's latent-cache write and the write of all layers'
+    after the scan under ``kv_cache``."""
+    cfg, text = _compile("deepseek_v2_lite_16b", step)
+    instructions, applied = _instructions(text)
+    arguments = {name for _, opcode, _, name in instructions
+                 if opcode == "parameter"}
+    m = cfg.mla
+    one_layer = {(B, S + NEW, m.kv_lora_rank), (B, S + NEW, m.qk_rope_dim)}
+    whole = {(cfg.n_layers,) + d for d in one_layer}
+    unscoped, seen, writes = [], set(), {"layer": 0, "whole": 0}
+    for comp, opcode, dims, op_name in instructions:
+        if (opcode == "parameter" or comp in applied
+                or (opcode == "bitcast" and op_name in arguments)
+                or op_name in XLA_NAMES):
+            continue
+        found = scopes_of(op_name)
+        seen.update(found)
+        if not found:
+            unscoped.append(f"{opcode} {op_name}")
+        if opcode == "dot":
+            assert MATMUL_SCOPES & set(found), (opcode, op_name)
+        if opcode == "dynamic-update-slice" and dims in one_layer | whole:
+            writes["layer" if dims in one_layer else "whole"] += 1
+            assert "kv_cache" in found, op_name
+    assert not unscoped, unscoped
+    assert {"embed", "layers", "attention", "logits", "moe", "route",
+            "dispatch", "experts", "combine", "mlp"} <= seen
+    if step == "decode":
+        assert writes["layer"] > 0 and writes["whole"] > 0, writes
+        assert {"kv_cache", "sample"} <= seen

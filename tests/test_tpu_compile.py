@@ -1,6 +1,6 @@
 """Compiles for a described TPU v5e, with no chip attached: the Pallas
-kernels at the widths ``chip_smoke.py`` runs, and the full-width decode
-steps of granite and Phi-3.  The TPU compiler refuses here what interpret
+kernels at the widths ``chip_smoke.py`` runs, the full-width decode
+steps of granite and Phi-3, and DeepSeek-V2-Lite's steps on four chips.  The TPU compiler refuses here what interpret
 mode accepts (a slice the tiling cannot prove aligned, more VMEM than a
 kernel may use) and a program that does not fit the chip's 16 GB of HBM.
 
@@ -29,14 +29,18 @@ HBM_BYTES = 16 * 10**9                        # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # any failure: no TPU compiler to describe it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -109,3 +113,46 @@ def test_decode_writes_the_cache_in_place_on_v5e(one_chip, arch, batch):
     copies = [line.strip() for line in compiled.as_text().splitlines()
               if whole.search(line)]
     assert not copies, copies
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_deepseek_steps_fit_four_v5e_chips(topo, step):
+    """DeepSeek-V2-Lite whole, at the four-chip benchmark cell's 16 slots
+    of 1024 + 256 positions, jitted as ``serve(mesh=...)`` jits it on a
+    data=1 x model=4 mesh: each chip's bytes fit its HBM, and the
+    expert-parallel combine and tensor-parallel all-reduces are in."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.launch import serve as SV
+    from repro.serve import make_prefill_step
+    cfg, B, P, N = get_config("deepseek_v2_lite_16b"), 16, 1024, 256
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(1, 4),
+                ("data", "model"))
+    rules, params_sh, cache_sh, rows = SV._on_mesh(cfg, mesh, B, P + N)
+    one = NamedSharding(mesh, PartitionSpec())
+
+    def placed(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            tree, shardings)
+    params = placed(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.key(0))[0]), params_sh)
+    with SV._installed(mesh, rules):
+        if step == "prefill":
+            f = jax.jit(make_prefill_step(cfg, max_seq=P + N),
+                        in_shardings=(params_sh, rows),
+                        out_shardings=(one, cache_sh))
+            args = (params, {"tokens": jax.ShapeDtypeStruct(
+                (B, P), jnp.int32, sharding=rows)})
+        else:
+            cache = placed(jax.eval_shape(
+                lambda: T.init_cache(cfg, B, P + N)[0]), cache_sh)
+            f = jax.jit(make_decode_step(cfg), donate_argnums=(1,),
+                        in_shardings=(params_sh, cache_sh, rows, one),
+                        out_shardings=(rows, cache_sh))
+            args = (params, cache,
+                    jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=rows),
+                    jax.ShapeDtypeStruct((), jnp.int32, sharding=one))
+        compiled = f.lower(*args).compile()
+    total = program_bytes(compiled.memory_analysis())
+    assert total < HBM_BYTES, total
+    assert re.search(r" all-reduce(?:-start)?\(", compiled.as_text())
