@@ -180,35 +180,46 @@ def windowed_attention(q, k, v, *, window: int, chunk: int = 512,
     return outs.transpose(1, 2, 0, 3, 4).reshape(B, H, Sq, D)
 
 
-def decode_attention(q, k_cache, v_cache, *, pos, window=0, k_pos=None):
+def decode_attention(q, k_cache, v_cache, *, pos, window=0, k_pos=None,
+                     new=None):
     """Single-token decode.  q: (B, H, 1, D); caches: (B, Hkv, S, D).
 
     ``pos`` is the current (scalar) position; keys at index > pos are masked.
     ``k_pos``: optional global positions of the cache slice (windowed path).
+    ``new``: the (k, v) of position ``pos``, each (B, Hkv, 1, D), attended
+    to as the key at ``pos``; the cache's entries from ``pos`` on are then
+    masked, so it need not hold them yet.
+
+    The G = H / Hkv query heads of a group meet their K/V head together, so
+    no cache entry is repeated per query head.  Scores, softmax and the
+    weighted sum are float32, the products exact (``HIGHEST``): the bf16
+    cache is upcast, the query and the weights are not rounded down.
     """
     B, H, _, D = q.shape
     _, Hkv, S, _ = k_cache.shape
-    G = H // Hkv
     scale = D ** -0.5
+    f32, exact = jnp.float32, jax.lax.Precision.HIGHEST
 
-    def expand(t):  # (B, Hkv, S, D) -> (B, H, S, D) broadcast (fused)
-        if G == 1:
-            return t
-        return jnp.broadcast_to(
-            t[:, :, None], (B, Hkv, G, S, D)).reshape(B, H, S, D)
+    def dot(spec, a, b):
+        return jnp.einsum(spec, a, b.astype(f32), precision=exact)
 
-    s = jnp.einsum("bhd,bhsd->bhs", q[:, :, 0].astype(jnp.float32),
-                   expand(k_cache).astype(jnp.float32)) * scale
+    qg = q.reshape(B, Hkv, H // Hkv, D).astype(f32)
+    s = dot("bhgd,bhsd->bhgs", qg, k_cache) * scale
     if k_pos is None:
         k_pos = jnp.arange(S)
-    mask = k_pos <= pos
+    mask = k_pos < pos if new is not None else k_pos <= pos
     if window is not None and not (isinstance(window, int) and window == 0):
         w_eff = jnp.where(window > 0, window, S + 1)
         mask &= (pos - k_pos) < w_eff
-    s = jnp.where(mask[None, None], s, NEG_INF)
+    s = jnp.where(mask, s, NEG_INF)
+    if new is not None:
+        s = jnp.concatenate([s, dot("bhgd,bhsd->bhgs", qg, new[0]) * scale],
+                            -1)
     w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhs,bhsd->bhd", w, expand(v_cache).astype(jnp.float32))
-    return out[:, :, None].astype(q.dtype)
+    out = dot("bhgs,bhsd->bhgd", w[..., :S], v_cache)
+    if new is not None:
+        out += dot("bhgs,bhsd->bhgd", w[..., S:], new[1])
+    return out.reshape(B, H, 1, D).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -285,6 +296,32 @@ def gqa_decode(p, x, cache_k, cache_v, cos, sin, *, cfg: ModelConfig, pos,
         out = decode_attention(q, cache_k, cache_v, pos=pos, window=window)
     out = out.transpose(0, 2, 1, 3).reshape(B, 1, H * hd)
     return out @ p["o"], cache_k, cache_v
+
+
+@jax.named_scope("attention")
+def gqa_decode_step(p, x, cache_k, cache_v, cos, sin, *, cfg: ModelConfig,
+                    pos, window=0):
+    """One-token decode that leaves the cache as it is.  x: (B, 1, d);
+    cache_[kv]: (B, Hkv, S, hd), filled below ``pos``.  The new position's
+    key and value are attended to as the key at ``pos`` and returned, for
+    the layer scan to write every layer's at once after it ends.
+
+    Returns (out, k_new, v_new), the last two (B, Hkv, 1, hd) in the
+    cache's dtype."""
+    B = x.shape[0]
+    H, Hkv = cfg.n_heads, cfg.n_kv_heads
+    hd = cfg.resolved_head_dim
+    q = (x @ p["q"]).reshape(B, 1, H, hd).transpose(0, 2, 1, 3)
+    k_new = (x @ p["k"]).reshape(B, 1, Hkv, hd).transpose(0, 2, 1, 3)
+    v_new = (x @ p["v"]).reshape(B, 1, Hkv, hd).transpose(0, 2, 1, 3)
+    if cos is not None:
+        q = _rope_heads(q, cos, sin)
+        k_new = _rope_heads(k_new, cos, sin)
+    k_new, v_new = k_new.astype(cache_k.dtype), v_new.astype(cache_v.dtype)
+    out = decode_attention(q, cache_k, cache_v, pos=pos, window=window,
+                           new=(k_new, v_new))
+    out = out.transpose(0, 2, 1, 3).reshape(B, 1, H * hd)
+    return out @ p["o"], k_new, v_new
 
 
 def _rope_heads(x, cos, sin):

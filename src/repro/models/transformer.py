@@ -634,12 +634,14 @@ def _decode_layers(cfg: ModelConfig, params, cache, x, pos):
         if mla:
             h, *upd = attn.mla_decode(pl["attn"], h_in, *mine, cos, sin,
                                       cfg=cfg, pos=pos)
+            with jax.named_scope("kv_cache"):   # the position axis: -2
+                new = tuple(jax.lax.dynamic_slice_in_dim(c, pos, 1,
+                                                         c.ndim - 2)
+                            for c in upd)
         else:
-            h, *upd = attn.gqa_decode(pl["attn"], h_in, *mine, cos, sin,
-                                      cfg=cfg, pos=pos, window=window)
-        with jax.named_scope("kv_cache"):   # the position axis: -2 of 3 or 4
-            new = tuple(jax.lax.dynamic_slice_in_dim(c, pos, 1, c.ndim - 2)
-                        for c in upd)
+            h, *new = attn.gqa_decode_step(pl["attn"], h_in, *mine, cos,
+                                           sin, cfg=cfg, pos=pos,
+                                           window=window)
         x = x + h
         if is_encdec:
             h, _, _ = attn.gqa_decode(

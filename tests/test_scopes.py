@@ -95,6 +95,11 @@ def test_every_operation_sits_under_a_documented_scope(arch, step):
     one_layer_cache = (B, cfg.n_kv_heads, S + NEW, cfg.resolved_head_dim)
     whole_cache = (cfg.n_layers,) + one_layer_cache
     whole_cache_writes = 0
+    # decode's scores of the cache's positions joined with the new key's,
+    # which takes the place of the cache's row at pos
+    merged_scores = (B, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads,
+                     S + NEW + 1)
+    merges = 0
     arguments = {name for _, opcode, _, name in instructions
                  if opcode == "parameter"}
     for comp, opcode, dims, op_name in instructions:
@@ -119,11 +124,18 @@ def test_every_operation_sits_under_a_documented_scope(arch, step):
         if opcode == "dynamic-update-slice" and dims == whole_cache:
             whole_cache_writes += 1
             assert "kv_cache" in found, op_name
+        if opcode == "concatenate" and dims == merged_scores:
+            merges += 1
+            assert "attention" in found, op_name
     assert not unscoped, unscoped
     assert dots > 0
     if step == "decode":
-        assert cache_writes > 0       # the new token's K and V
-        assert whole_cache_writes > 0  # ... and all layers' into the cache
+        # the new token's K and V are merged into the attention, and all
+        # layers' go into the cache once, after the scan: no layer writes
+        # a slice of its own
+        assert cache_writes == 0
+        assert merges > 0
+        assert whole_cache_writes > 0
         assert {"kv_cache", "sample"} <= seen
     if step == "train":
         assert {"loss", "optimizer"} <= seen

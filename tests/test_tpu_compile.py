@@ -95,24 +95,58 @@ def test_granite_decode_compiles_for_v5e(one_chip):
     assert total < HBM_BYTES, total
 
 
-@pytest.mark.parametrize("arch,batch", [("granite_moe_1b_a400m", 32),
-                                        ("phi3_mini_3_8b", 4)])
-def test_decode_writes_the_cache_in_place_on_v5e(one_chip, arch, batch):
-    """At the chat cells' shapes the layer scan neither stacks the cache as
-    its output nor copies it back: the step's temporaries stay under one
-    K cache, and no copy has the whole cache's shape."""
-    cfg, max_seq = get_config(arch), 1280
+@pytest.mark.parametrize("arch,batch,max_seq", [
+    pytest.param("granite_moe_1b_a400m", 32, 1280,
+                 id="granite_moe_1b_a400m-32"),
+    pytest.param("phi3_mini_3_8b", 4, 1280, id="phi3_mini_3_8b-4"),
+    pytest.param("granite_moe_1b_a400m", 8, 3872,
+                 id="granite_moe_1b_a400m-8-longdoc")])
+def test_decode_writes_the_cache_in_place_on_v5e(one_chip, arch, batch,
+                                                 max_seq):
+    """At the chat and longdoc cells' shapes the layer scan neither stacks
+    the cache as its output nor copies it back: the step's temporaries
+    stay under one K cache, and no copy has the whole cache's shape.  The
+    attention reads each layer of the cache where it lies: no array of one
+    layer's shape (the cache's order or the position-minor one, any dtype,
+    a float32 upcast included) or of its broadcast to every query head of
+    a group is written to memory; such shapes live inside fusions only."""
+    cfg = get_config(arch)
     compiled = _compile_decode(one_chip, cfg, batch, max_seq)
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_seq,
-             cfg.resolved_head_dim)
+    Hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    G = cfg.n_heads // Hkv
+    shape = (cfg.n_layers, batch, Hkv, max_seq, hd)
     one_cache = 2 * int(np.prod(shape))                   # bf16
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < one_cache, (temp, one_cache)
-    dims = ",".join(map(str, shape))
-    whole = re.compile(r"= bf16\[%s\]\S* copy\(" % dims)
-    copies = [line.strip() for line in compiled.as_text().splitlines()
+    text = compiled.as_text()
+
+    def dims(*d):
+        return ",".join(map(str, d))
+    whole = re.compile(r"= bf16\[%s\]\S* copy\(" % dims(*shape))
+    copies = [line.strip() for line in text.splitlines()
               if whole.search(line)]
     assert not copies, copies
+    one_layer = [dims(batch, Hkv, max_seq, hd), dims(batch, Hkv, hd, max_seq),
+                 dims(batch, Hkv, G, max_seq, hd), dims(batch, Hkv * G,
+                                                         max_seq, hd)]
+    layer = re.compile(r"= \w+\[(?:1,)*(?:%s)\]" % "|".join(one_layer))
+    found = [line[:200] for _, line in _materialized(text)
+             if layer.search(line)]
+    assert not found, found
+
+
+def _materialized(text):
+    """(computation, instruction) of every instruction outside the fused
+    computations of an optimized HLO module's text: the arrays that are
+    written to memory."""
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) ", line)
+        if head:
+            comp = head.group(1)
+        elif comp and not comp.startswith("fused") and re.match(
+                r"^\s+(?:ROOT )?%\S+ = ", line):
+            yield comp, line.strip()
 
 
 @pytest.mark.parametrize("step", ["prefill", "decode"])
