@@ -2,7 +2,7 @@
 parallel helpers, gradient compression, and pipeline parallelism.
 
 Modules:
-  context   — process-global mesh + PerfFlags (the perf-ablation switches)
+  context   — the process-global device mesh
   sharding  — logical-axis -> mesh-axis rule engine with divisibility guards
   tp        — tensor-parallel projection helper (closes a TP region)
   compress  — int8 block-quantized gradient all-reduce with error feedback

@@ -1,48 +1,17 @@
-"""Process-global distribution context: active mesh + performance flags.
+"""Process-global distribution context: the active device mesh.
 
-The launchers (``repro.launch.*``) install a mesh and a ``PerfFlags`` set
-before tracing; model code reads them through the accessors here so the same
-forward functions serve the baseline and every §Perf ablation without
-threading flags through call signatures.
+The launchers (``repro.launch.*``) install a mesh before tracing; model
+code reads it through the accessors here rather than through its call
+signatures.
 
-Everything defaults to "no mesh, baseline flags" so single-device tests and
-benchmarks need no setup.
+Everything defaults to "no mesh" so single-device tests and benchmarks
+need no setup.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field, fields
 from typing import Optional, Tuple, Union
 
-
-@dataclass(frozen=True)
-class PerfFlags:
-    """Beyond-paper optimization switches (all default to baseline).
-
-    attn_remat_chunk      remat the online-softmax body (flash-style bwd)
-    windowed_attention    static sliding-window paths for local:global archs
-    seq_sharded_residual  Megatron-SP residual stream sharded over 'model'
-    bf16_tp_collectives   cast TP collectives to bf16 on the wire
-    ssm_impl              'scan' (recurrent) | 'chunked' (SSD-style blocks)
-    moe_dispatch          'gather' (index dispatch) | 'einsum' (one-hot)
-    """
-    attn_remat_chunk: bool = False
-    windowed_attention: bool = False
-    seq_sharded_residual: bool = False
-    bf16_tp_collectives: bool = False
-    ssm_impl: str = "scan"
-    moe_dispatch: str = "gather"
-
-    def __post_init__(self):
-        # CLI override strings ("ssm_impl=chunked", bare flags -> True) come
-        # through as str; normalize bool-typed fields.
-        for f in fields(self):
-            v = getattr(self, f.name)
-            if f.type == "bool" and isinstance(v, str):
-                object.__setattr__(
-                    self, f.name, v.lower() in ("1", "true", "yes", "on"))
-
-
-_STATE = {"mesh": None, "flags": PerfFlags()}
+_STATE = {"mesh": None}
 
 
 def set_mesh(mesh) -> None:
@@ -52,14 +21,6 @@ def set_mesh(mesh) -> None:
 
 def get_mesh():
     return _STATE["mesh"]
-
-
-def set_perf_flags(flags: PerfFlags) -> None:
-    _STATE["flags"] = flags
-
-
-def perf_flags() -> PerfFlags:
-    return _STATE["flags"]
 
 
 def mesh_axis_size(name: str) -> int:

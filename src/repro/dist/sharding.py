@@ -107,7 +107,6 @@ def default_rules(mesh) -> Rules:
         "head_dim": None,
         "experts": "model",
         "kv_seq": None,
-        "seq_model": "model",
         "layers": None,
         "kv_lora": None,
         "ssm_heads": None,
@@ -153,7 +152,6 @@ def rules_for(cfg: ModelConfig, shape: ShapeConfig, mesh) -> Rules:
     table["head_dim"] = tp(hd) if table["kv_heads"] is None else None
     table["d_ff"] = tp(cfg.d_ff)
     table["vocab"] = tp(cfg.vocab)
-    table["seq_model"] = "model" if model > 1 and S % model == 0 else None
     if cfg.ssm is not None:
         table["d_inner"] = tp(cfg.ssm.expand * cfg.d_model)
     else:

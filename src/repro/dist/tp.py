@@ -27,10 +27,5 @@ def tp_project(x, w, axis_name: str = "model"):
     """x @ w, reduced over ``axis_name`` when that axis is explicitly bound."""
     out = x @ w
     if dist_ctx.mesh_axis_size(axis_name) > 1 and _axis_bound(axis_name):
-        if dist_ctx.perf_flags().bf16_tp_collectives:
-            import jax.numpy as jnp
-            out = jax.lax.psum(out.astype(jnp.bfloat16),
-                               axis_name).astype(x.dtype)
-        else:
-            out = jax.lax.psum(out, axis_name)
+        out = jax.lax.psum(out, axis_name)
     return out

@@ -126,24 +126,11 @@ def input_specs(cfg: ModelConfig, shape: ShapeConfig, rules: Rules):
 
 
 def lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
-               n_microbatches: int = 1, donate: bool = True,
-               perf: str = ""):
-    """Returns (lowered, rules).  Raises on sharding/lowering failure.
-
-    ``perf``: comma-separated PerfFlags overrides, e.g.
-    "attn_remat_chunk,bf16_tp_collectives,windowed_attention,ssm_impl=chunked"
-    """
+               n_microbatches: int = 1, donate: bool = True):
+    """Returns (lowered, rules).  Raises on sharding/lowering failure."""
     rules = rules_for(cfg, shape, mesh)
     set_active_rules(rules)
     dist_ctx.set_mesh(mesh)
-    kw = {}
-    for item in filter(None, perf.split(",")):
-        if "=" in item:
-            k, v = item.split("=", 1)
-            kw[k] = v
-        else:
-            kw[item] = True
-    dist_ctx.set_perf_flags(dist_ctx.PerfFlags(**kw))
     params, axes = abstract_params(cfg)
     param_sh = rules.tree_shardings(axes, params)
 
@@ -185,20 +172,19 @@ def lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh, *,
 
 def run_cell(arch: str, shape: ShapeConfig, mesh, mesh_name: str,
              out_dir: Path, *, save_hlo: bool = False,
-             n_microbatches: int = 1, perf: str = ""):
+             n_microbatches: int = 1):
     """Lower + compile one cell; return the result record."""
     cfg = get_config(arch)
     runnable, why = cell_is_runnable(cfg, shape)
     rec = {"arch": arch, "shape": shape.name, "mesh": mesh_name,
-           "kind": shape.kind, "perf": perf, "timestamp": time.time()}
+           "kind": shape.kind, "timestamp": time.time()}
     if not runnable:
         rec.update(status="skip", reason=why)
         return rec
     t0 = time.time()
     try:
         lowered, rules = lower_cell(cfg, shape, mesh,
-                                    n_microbatches=n_microbatches,
-                                    perf=perf)
+                                    n_microbatches=n_microbatches)
         t_lower = time.time() - t0
         print(f"  lowered in {t_lower:.1f}s", flush=True)
         compiled = lowered.compile()
@@ -235,7 +221,6 @@ def run_cell(arch: str, shape: ShapeConfig, mesh, mesh_name: str,
     finally:
         set_active_rules(None)
         dist_ctx.set_mesh(None)
-        dist_ctx.set_perf_flags(dist_ctx.PerfFlags())
     return rec
 
 
@@ -259,10 +244,6 @@ def main():
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--save-hlo", action="store_true")
     ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--perf", default="",
-                    help="PerfFlags list, e.g. attn_remat_chunk,"
-                         "bf16_tp_collectives,windowed_attention,"
-                         "ssm_impl=chunked")
     args = ap.parse_args()
 
     out_dir = Path(args.out)
@@ -284,8 +265,6 @@ def main():
                 key = f"{arch}|{shape.name}|{mesh_name}"
                 if args.microbatches > 1:
                     key += f"|mb{args.microbatches}"
-                if args.perf:
-                    key += f"|{args.perf}"
                 if key in results and not args.force \
                         and results[key]["status"] in ("ok", "skip"):
                     print(f"[cached] {key}: {results[key]['status']}")
@@ -293,8 +272,7 @@ def main():
                 print(f"[run] {key} ...", flush=True)
                 rec = run_cell(arch, shape, mesh, mesh_name, out_dir,
                                save_hlo=args.save_hlo,
-                               n_microbatches=args.microbatches,
-                               perf=args.perf)
+                               n_microbatches=args.microbatches)
                 results[key] = rec
                 res_path.write_text(json.dumps(results, indent=1))
                 status = rec["status"]

@@ -115,14 +115,6 @@ def chunked_attention(q, k, v, *, causal=True, window=0, q_offset=0,
             "bhqc,bhcd->bhqd", p, expand(v_i).astype(jnp.float32))
         return (m_new, l_new, acc_new), None
 
-    from repro.dist import context as dist_ctx
-    if dist_ctx.perf_flags().attn_remat_chunk:
-        # flash-style backward: recompute the (Sq, chunk) score tile in the
-        # bwd pass instead of stacking it per chunk (§Perf: removes the
-        # n_chunks x B x H x Sq x chunk fp32 residual the autodiff of the
-        # plain scan materializes)
-        body = jax.checkpoint(body)
-
     m0 = jnp.full((B, H, Sq), NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((B, H, Sq), dtype=jnp.float32)
     a0 = jnp.zeros((B, H, Sq, D), dtype=jnp.float32)
@@ -131,61 +123,10 @@ def chunked_attention(q, k, v, *, causal=True, window=0, q_offset=0,
     return out.astype(q.dtype)
 
 
-def windowed_attention(q, k, v, *, window: int, chunk: int = 512,
-                       q_offset=0):
-    """Sliding-window attention with STATIC window: each query chunk
-    attends only to its own and the previous KV chunk (requires
-    window <= chunk), so compute and traffic scale with O(S * window)
-    instead of O(S^2) — the gemma3 local-layer path (§Perf).
-
-    q: (B, H, Sq, D); k, v: (B, Hkv, Skv, D), Sq == Skv.
-    """
-    B, H, Sq, D = q.shape
-    _, Hkv, Skv, _ = k.shape
-    G = H // Hkv
-    assert window <= chunk, (window, chunk)
-    chunk = min(chunk, Sq)
-    assert Sq % chunk == 0
-    nq = Sq // chunk
-    scale = D ** -0.5
-    # pad one chunk of zeros on the left so every q-chunk sees 2 chunks
-    kp = jnp.pad(k, ((0, 0), (0, 0), (chunk, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, 0), (chunk, 0), (0, 0)))
-    qc = q.reshape(B, H, nq, chunk, D).transpose(2, 0, 1, 3, 4)
-
-    def expand(t, c):
-        if G == 1:
-            return t
-        return jnp.broadcast_to(t[:, :, None], (B, Hkv, G, c, D)) \
-            .reshape(B, H, c, D)
-
-    def body(_, xs):
-        j, q_j = xs
-        k_j = jax.lax.dynamic_slice_in_dim(kp, j * chunk, 2 * chunk, 2)
-        v_j = jax.lax.dynamic_slice_in_dim(vp, j * chunk, 2 * chunk, 2)
-        q_pos = q_offset + j * chunk + jnp.arange(chunk)
-        k_pos = q_offset + (j - 1) * chunk + jnp.arange(2 * chunk)
-        s = jnp.einsum("bhqd,bhcd->bhqc", q_j.astype(jnp.float32),
-                       expand(k_j, 2 * chunk).astype(jnp.float32)) * scale
-        mask = (q_pos[:, None] >= k_pos[None, :]) \
-            & ((q_pos[:, None] - k_pos[None, :]) < window) \
-            & (k_pos >= 0)[None, :]
-        s = jnp.where(mask[None, None], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqc,bhcd->bhqd", p,
-                       expand(v_j, 2 * chunk).astype(jnp.float32))
-        return (), o.astype(q.dtype)
-
-    _, outs = jax.lax.scan(body, (), (jnp.arange(nq), qc))
-    return outs.transpose(1, 2, 0, 3, 4).reshape(B, H, Sq, D)
-
-
-def decode_attention(q, k_cache, v_cache, *, pos, window=0, k_pos=None,
-                     new=None):
+def decode_attention(q, k_cache, v_cache, *, pos, window=0, new=None):
     """Single-token decode.  q: (B, H, 1, D); caches: (B, Hkv, S, D).
 
     ``pos`` is the current (scalar) position; keys at index > pos are masked.
-    ``k_pos``: optional global positions of the cache slice (windowed path).
     ``new``: the (k, v) of position ``pos``, each (B, Hkv, 1, D), attended
     to as the key at ``pos``; the cache's entries from ``pos`` on are then
     masked, so it need not hold them yet.
@@ -205,8 +146,7 @@ def decode_attention(q, k_cache, v_cache, *, pos, window=0, k_pos=None,
 
     qg = q.reshape(B, Hkv, H // Hkv, D).astype(f32)
     s = dot("bhgd,bhsd->bhgs", qg, k_cache) * scale
-    if k_pos is None:
-        k_pos = jnp.arange(S)
+    k_pos = jnp.arange(S)
     mask = k_pos < pos if new is not None else k_pos <= pos
     if window is not None and not (isinstance(window, int) and window == 0):
         w_eff = jnp.where(window > 0, window, S + 1)
@@ -228,11 +168,10 @@ def decode_attention(q, k_cache, v_cache, *, pos, window=0, k_pos=None,
 
 @jax.named_scope("attention")
 def gqa_forward(p, x, cos, sin, *, cfg: ModelConfig, causal=True, window=0,
-                q_offset=0, xa=None, static_window=None):
+                q_offset=0, xa=None):
     """Full-sequence attention (train/prefill).  Returns (out, (k, v)).
 
     ``xa``: encoder output for cross attention (k/v from xa, no causal mask).
-    ``static_window``: compile-time window -> O(S*window) windowed path.
     """
     from repro.dist.tp import tp_project
     B, S, d = x.shape
@@ -246,56 +185,21 @@ def gqa_forward(p, x, cos, sin, *, cfg: ModelConfig, causal=True, window=0,
     if cos is not None and xa is None:
         q = _rope_heads(q, cos, sin)
         k = _rope_heads(k, cos, sin)
-    if static_window and xa is None:
-        out = windowed_attention(q, k, v, window=static_window,
-                                 q_offset=q_offset)
-    else:
-        out = chunked_attention(q, k, v, causal=causal and xa is None,
-                                window=window, q_offset=q_offset)
+    out = chunked_attention(q, k, v, causal=causal and xa is None,
+                            window=window, q_offset=q_offset)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     return tp_project(out, p["o"]), (k, v)
 
 
 @jax.named_scope("attention")
-def gqa_decode(p, x, cache_k, cache_v, cos, sin, *, cfg: ModelConfig, pos,
-               window=0, xa_kv=None, static_window=None):
-    """One-token decode.  x: (B, 1, d).  cache_[kv]: (B, Hkv, S, hd).
-
-    ``static_window``: compile-time window — the attention reads only a
-    window-sized SLICE of the cache (O(window) instead of O(S) per token;
-    the gemma3 local-layer decode path, §Perf)."""
-    B, _, d = x.shape
-    H, Hkv = cfg.n_heads, cfg.n_kv_heads
-    hd = cfg.resolved_head_dim
+def cross_decode(p, x, xk, xv, *, cfg: ModelConfig):
+    """One token's cross-attention over the encoder's K/V, each
+    (B, Hkv, n_ctx, hd), every key visible.  x: (B, 1, d)."""
+    B = x.shape[0]
+    H, hd = cfg.n_heads, cfg.resolved_head_dim
     q = (x @ p["q"]).reshape(B, 1, H, hd).transpose(0, 2, 1, 3)
-    if xa_kv is not None:
-        k, v = xa_kv  # cross-attention: precomputed encoder KV
-        out = decode_attention(q, k, v, pos=k.shape[2] - 1)
-        out = out.transpose(0, 2, 1, 3).reshape(B, 1, H * hd)
-        return out @ p["o"], cache_k, cache_v
-    k_new = (x @ p["k"]).reshape(B, 1, Hkv, hd).transpose(0, 2, 1, 3)
-    v_new = (x @ p["v"]).reshape(B, 1, Hkv, hd).transpose(0, 2, 1, 3)
-    if cos is not None:
-        q = _rope_heads(q, cos, sin)
-        k_new = _rope_heads(k_new, cos, sin)
-    with jax.named_scope("kv_cache"):
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k_new.astype(cache_k.dtype), (0, 0, pos, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v_new.astype(cache_v.dtype), (0, 0, pos, 0))
-    if static_window:
-        S = cache_k.shape[2]
-        w = min(static_window, S)
-        with jax.named_scope("kv_cache"):
-            start = jnp.clip(pos - w + 1, 0, S - w)
-            k_win = jax.lax.dynamic_slice_in_dim(cache_k, start, w, 2)
-            v_win = jax.lax.dynamic_slice_in_dim(cache_v, start, w, 2)
-        out = decode_attention(q, k_win, v_win, pos=pos,
-                               k_pos=start + jnp.arange(w))
-    else:
-        out = decode_attention(q, cache_k, cache_v, pos=pos, window=window)
-    out = out.transpose(0, 2, 1, 3).reshape(B, 1, H * hd)
-    return out @ p["o"], cache_k, cache_v
+    out = decode_attention(q, xk, xv, pos=xk.shape[2] - 1)
+    return out.transpose(0, 2, 1, 3).reshape(B, 1, H * hd) @ p["o"]
 
 
 @jax.named_scope("attention")

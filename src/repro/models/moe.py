@@ -1,23 +1,20 @@
 """Mixture-of-Experts with expert parallelism.
 
-Design (see DESIGN.md §2, "multi-accelerator worker pool" row): experts are
-sharded over the ``model`` mesh axis.  Routing is computed redundantly on
-every model-rank for its local batch shard; each rank gathers only the tokens
-assigned to ITS experts into fixed-capacity buffers (the SMAUG command-queue
-analogue: tiles whose partial results belong to one expert land on that
-expert's queue), computes them, and the per-rank partial outputs are combined
-with one psum over ``model`` — the same collective cost as the TP all-reduce
-it replaces for a dense MLP.
+Experts are sharded over the ``model`` mesh axis.  Routing is computed
+redundantly on every model-rank for its local batch shard; each rank gathers
+only the tokens assigned to ITS experts into fixed-capacity buffers (the
+SMAUG command-queue analogue: tiles whose partial results belong to one
+expert land on that expert's queue), computes them, and the per-rank partial
+outputs are combined with one psum over ``model`` — the same collective cost
+as the TP all-reduce it replaces for a dense MLP.  Without such a mesh the
+whole expert set runs as the one rank of one.
 
 Dispatch is gather/scatter-index based (no one-hot dispatch einsums), so HLO
-FLOPs stay close to the useful expert FLOPs; this is the "beyond-paper"
-default, with `dispatch="einsum"` kept as the naive baseline for the §Perf
-comparison.
+FLOPs stay close to the useful expert FLOPs.
 """
 from __future__ import annotations
 
 import math
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -135,24 +132,6 @@ def _expert_ffn(p_gate, p_up, p_down, xb, activation="swiglu"):
     return jnp.einsum("ecf,efd->ecd", g * u, p_down)
 
 
-def _moe_local(p, x, cfg: ModelConfig, ep_rank, ep_size, psum_axis):
-    """Per-shard MoE.  x: (T, d) local tokens.  Returns (out (T, d), aux)."""
-    e = cfg.moe
-    T = x.shape[0]
-    e_local = e.n_experts // ep_size
-    e_start = ep_rank * e_local
-    capacity = max(1, math.ceil(T * e.top_k * e.capacity_factor / e.n_experts))
-
-    w, idx, aux = _route(x.astype(jnp.float32), p["router"], e)
-    xb, slot_of = _dispatch(x, idx, e.n_experts, e_start, e_local, capacity)
-    with jax.named_scope("experts"):
-        gate_l = jax.lax.dynamic_slice_in_dim(p["gate"], e_start, e_local, 0)
-        up_l = jax.lax.dynamic_slice_in_dim(p["up"], e_start, e_local, 0)
-        down_l = jax.lax.dynamic_slice_in_dim(p["down"], e_start, e_local, 0)
-    yb = _expert_ffn(gate_l, up_l, down_l, xb)
-    return _combine(yb, w, slot_of, psum_axis, x.dtype), aux
-
-
 @jax.named_scope("moe")
 def moe_apply(p, x, cfg: ModelConfig):
     """x: (B, S, d) -> (out (B, S, d), aux losses dict).
@@ -162,8 +141,6 @@ def moe_apply(p, x, cfg: ModelConfig):
     """
     B, S, d = x.shape
     e = cfg.moe
-    if dist_ctx.perf_flags().moe_dispatch == "einsum":
-        return moe_apply_einsum(p, x, cfg)  # ablation baseline
     mesh = dist_ctx.get_mesh()
     tp = dist_ctx.mesh_axis_size("model")
     use_ep = (mesh is not None and tp > 1 and e.n_experts % tp == 0)
@@ -197,7 +174,7 @@ def moe_apply(p, x, cfg: ModelConfig):
         )(x, p["router"], p["gate"][None], p["up"][None], p["down"][None])
         aux = {"load_balance": lb, "router_z": rz}
     else:
-        out, aux = _moe_local(p, x.reshape(B * S, d), cfg, 0, 1, None)
+        out, aux = _moe_local_shard(p, x.reshape(B * S, d), cfg, 0, 1, None)
         out = out.reshape(B, S, d)
 
     if "shared" in p:
@@ -207,7 +184,10 @@ def moe_apply(p, x, cfg: ModelConfig):
 
 
 def _moe_local_shard(p, x, cfg, ep_rank, ep_size, psum_axis):
-    """Like _moe_local but expert params are ALREADY the local shard."""
+    """The MoE of rank ``ep_rank`` of ``ep_size``, whose expert params hold
+    its own ``n_experts / ep_size`` experts (all of them at one rank).
+    x: (T, d) local tokens.  Returns (out (T, d), aux): out is this rank's
+    share of the sum over the ranks, psummed over ``psum_axis`` if given."""
     e = cfg.moe
     T = x.shape[0]
     e_local = e.n_experts // ep_size
@@ -217,41 +197,3 @@ def _moe_local_shard(p, x, cfg, ep_rank, ep_size, psum_axis):
     xb, slot_of = _dispatch(x, idx, e.n_experts, e_start, e_local, capacity)
     yb = _expert_ffn(p["gate"], p["up"], p["down"], xb)
     return _combine(yb, w, slot_of, psum_axis, x.dtype), aux
-
-
-# ---------------------------------------------------------------------------
-# naive einsum dispatch (paper-faithful "simple" baseline for §Perf)
-
-
-def moe_apply_einsum(p, x, cfg: ModelConfig):
-    """One-hot dispatch-einsum MoE (mesh-tensorflow style).  Kept as the
-    baseline the §Perf iteration improves on: its dispatch einsums dwarf the
-    useful expert FLOPs at top_k>2."""
-    B, S, d = x.shape
-    e = cfg.moe
-    T = B * S
-    xf = x.reshape(T, d)
-    capacity = max(1, math.ceil(T * e.top_k * e.capacity_factor / e.n_experts))
-    w, idx, aux = _route(xf.astype(jnp.float32), p["router"], e)
-    # dispatch tensor (T, E, C)
-    with jax.named_scope("dispatch"):
-        onehot_e = jax.nn.one_hot(idx, e.n_experts,
-                                  dtype=jnp.float32)           # (T,k,E)
-        pos = jnp.cumsum(onehot_e.reshape(T * e.top_k, e.n_experts),
-                         axis=0) - 1
-        pos = pos.reshape(T, e.top_k, e.n_experts)
-        pos_tk = jnp.sum(pos * onehot_e, axis=-1)              # (T, k)
-        within = (pos_tk < capacity)[..., None]                # (T, k, 1)
-        pos_onehot = jax.nn.one_hot(pos_tk, capacity, dtype=jnp.float32)
-        disp = jnp.einsum("tke,tkc->tec", onehot_e * within, pos_onehot)
-        xb = jnp.einsum("tec,td->ecd", disp,
-                        xf.astype(jnp.float32)).astype(x.dtype)
-    yb = _expert_ffn(p["gate"], p["up"], p["down"], xb)
-    with jax.named_scope("combine"):
-        comb = jnp.einsum("tke,tkc,tk->tec", onehot_e * within, pos_onehot, w)
-        out = jnp.einsum("tec,ecd->td", comb, yb.astype(jnp.float32))
-        out = out.reshape(B, S, d).astype(x.dtype)
-    if "shared" in p:
-        from repro.models.layers import mlp_apply
-        out = out + mlp_apply(p["shared"], x, "swiglu")
-    return out, aux

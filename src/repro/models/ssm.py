@@ -1,19 +1,15 @@
 """State-space models: Mamba1 (selective scan) and Mamba2 (SSD).
 
-Two sequence-mixing implementations are provided for Mamba1:
-  - ``scan``    : lax.scan over time (paper-faithful simple baseline; HBM
-                  traffic O(seq) state round-trips — the memory-bound case
-                  the §Perf iteration attacks),
-  - ``chunked`` : lax.scan over chunks with an associative scan inside each
-                  chunk (parallel depth O(log c)); the Pallas kernel in
-                  repro.kernels.mamba_scan is the TPU realization.
+Mamba1 mixes the sequence with a lax.scan over time that carries the
+(B, d_inner, d_state) state: HBM traffic is O(seq) state round-trips, the
+memory-bound term the Pallas kernel in repro.kernels.mamba_scan keeps in
+VMEM.
 
 Mamba2 uses the chunked SSD algorithm directly.
 """
 from __future__ import annotations
 
 import math
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -116,13 +112,13 @@ def _ssm_coeffs1(p, xz, cfg):
 
 
 @jax.named_scope("ssm")
-def mamba1_forward(p, x_seq, cfg: ModelConfig, impl="scan", state=None):
+def mamba1_forward(p, x_seq, cfg: ModelConfig, state=None):
     """x_seq: (B, S, d_model) -> (out, final_state dict(conv, ssm)).
 
     state (decode carry): dict(conv (B,d_in,k-1), ssm (B,d_in,N)).
     """
     s = cfg.ssm
-    B, S, _ = x_seq.shape
+    B = x_seq.shape[0]
     d_in = s.expand * cfg.d_model
     N = s.d_state
     xz = x_seq @ p["in_proj"]
@@ -137,60 +133,16 @@ def mamba1_forward(p, x_seq, cfg: ModelConfig, impl="scan", state=None):
     h0 = (jnp.zeros((B, d_in, N), jnp.float32) if state is None
           else state["ssm"])
 
-    if impl == "scan":
-        def step(h, inp):
-            da_t, dbx_t, C_t = inp
-            h = da_t * h + dbx_t
-            y = jnp.einsum("bdn,bn->bd", h, C_t)
-            return h, y
-        hT, ys = jax.lax.scan(
-            step, h0,
-            (da.transpose(1, 0, 2, 3), dbx.transpose(1, 0, 2, 3),
-             Cm.transpose(1, 0, 2)))
-        y = ys.transpose(1, 0, 2)                              # (B,S,d_in)
-    elif impl.startswith("unroll"):
-        # §Perf: U sequential steps per scan iteration — amortizes the
-        # per-step state round-trip and slice/stack bookkeeping U-fold
-        # while staying mathematically identical to the plain scan
-        U = int(impl[len("unroll"):] or 8)
-        assert S % U == 0, (S, U)
-        shape_u = (B, S // U, U)
-
-        def chunks_u(t):
-            return t.reshape(*shape_u, *t.shape[2:]).transpose(
-                1, 2, 0, *range(3, t.ndim + 1))
-
-        da_u, dbx_u = chunks_u(da), chunks_u(dbx)
-        C_u = chunks_u(Cm)
-
-        def step(h, inp):
-            da_i, dbx_i, C_i = inp           # (U,B,d,N),(U,B,d,N),(U,B,N)
-            ys = []
-            for t in range(U):
-                h = da_i[t] * h + dbx_i[t]
-                ys.append(jnp.einsum("bdn,bn->bd", h, C_i[t]))
-            return h, jnp.stack(ys)
-        hT, ys = jax.lax.scan(step, h0, (da_u, dbx_u, C_u))
-        y = ys.transpose(2, 0, 1, 3).reshape(B, S, d_in)  # (S/U,U,B,d)->(B,S,d)
-    else:  # chunked: associative scan within chunks, sequential across
-        c = min(getattr(s, "chunk", 256), S)
-        assert S % c == 0, (S, c)
-        nc = S // c
-        da_c = da.reshape(B, nc, c, d_in, N).transpose(1, 0, 2, 3, 4)
-        dbx_c = dbx.reshape(B, nc, c, d_in, N).transpose(1, 0, 2, 3, 4)
-        C_c = Cm.reshape(B, nc, c, N).transpose(1, 0, 2, 3)
-
-        def chunk_step(h, inp):
-            da_i, dbx_i, C_i = inp                 # (B,c,d,N),(B,c,d,N),(B,c,N)
-            # h contributes da-prefix-scaled; combine with intra-chunk scan
-            def comb(l, r):
-                return (l[0] * r[0], l[1] * r[0] + r[1])
-            pa, pb = jax.lax.associative_scan(comb, (da_i, dbx_i), axis=1)
-            hs = pa * h[:, None] + pb              # (B,c,d,N) states
-            y = jnp.einsum("bcdn,bcn->bcd", hs, C_i)
-            return hs[:, -1], y
-        hT, ys = jax.lax.scan(chunk_step, h0, (da_c, dbx_c, C_c))
-        y = ys.transpose(1, 0, 2, 3).reshape(B, S, d_in)
+    def step(h, inp):
+        da_t, dbx_t, C_t = inp
+        h = da_t * h + dbx_t
+        y = jnp.einsum("bdn,bn->bd", h, C_t)
+        return h, y
+    hT, ys = jax.lax.scan(
+        step, h0,
+        (da.transpose(1, 0, 2, 3), dbx.transpose(1, 0, 2, 3),
+         Cm.transpose(1, 0, 2)))
+    y = ys.transpose(1, 0, 2)                                  # (B,S,d_in)
 
     y = y + p["D"][None, None] * xf
     y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x_seq.dtype)

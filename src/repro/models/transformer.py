@@ -10,7 +10,7 @@ All architectures share the same entry points:
 
 Layers are STACKED along a leading axis and executed with lax.scan (+remat),
 which keeps HLO size O(1) in depth and forms the loop tree the SMAUG-style
-sampled simulator unsamples through (DESIGN.md §7).
+sampled simulator unsamples through.
 """
 from __future__ import annotations
 
@@ -205,19 +205,10 @@ def _backbone(cfg: ModelConfig, p, x, xa=None, collect=False):
     cos, sin = _rope_for(cfg, 0, x.shape[1])
 
     if cfg.family == "ssm":
-        from repro.dist import context as dist_ctx
-        impl = dist_ctx.perf_flags().ssm_impl
-        sp_on = dist_ctx.perf_flags().seq_sharded_residual
-
-        def fwd(pp, xx, cc):
-            if cfg.ssm.version == 1:
-                return ssm_mod.mamba1_forward(pp, xx, cc, impl=impl)
-            return ssm_mod.mamba2_forward(pp, xx, cc)
+        fwd = (ssm_mod.mamba1_forward if cfg.ssm.version == 1
+               else ssm_mod.mamba2_forward)
 
         def body(x, pl):
-            if sp_on:  # Megatron-SP residual (see dense branch)
-                from repro.dist.sharding import constrain
-                x = constrain(x, ("batch", "seq_model", None))
             h, st = fwd(pl["ssm"], apply_norm(cfg.norm, x, pl["norm1"]), cfg)
             return x + h, (st if collect else ())
         x, sts = jax.lax.scan(jax.checkpoint(body), x, p["layers"])
@@ -250,85 +241,9 @@ def _backbone(cfg: ModelConfig, p, x, xa=None, collect=False):
 
     windows = _window_schedule(cfg)
 
-    # §Perf: static-window grouped scan for local:global archs (gemma3) —
-    # unrolls each (ratio local + 1 global) group so local layers take the
-    # O(S*window) windowed-attention path instead of masked full attention
-    from repro.dist import context as dist_ctx
-    flags = dist_ctx.perf_flags()
-    if (cfg.local_global_ratio > 0 and flags.windowed_attention
-            and cfg.mla is None and xa is None and cfg.window > 0):
-        grp = cfg.local_global_ratio + 1
-        nsb = cfg.n_layers // grp
-        tail = cfg.n_layers - nsb * grp
-        win_sched = [0 if (i + 1) % grp == 0 else cfg.window
-                     for i in range(cfg.n_layers)]  # static python ints
-
-        def one_layer(x, lb, rz, pl, sw):
-            h_in = apply_norm(cfg.norm, x, pl["norm1"])
-            h, kv = attn.gqa_forward(pl["attn"], h_in, cos, sin, cfg=cfg,
-                                     causal=True, static_window=sw)
-            x = x + h
-            h_in = apply_norm(cfg.norm, x, pl["norm2"])
-            if "moe" in pl:
-                h, aux = moe_mod.moe_apply(pl["moe"], h_in, cfg)
-                lb, rz = lb + aux["load_balance"], rz + aux["router_z"]
-            else:
-                h = mlp_apply(pl["mlp"], h_in, cfg.activation)
-            return x + h, lb, rz, kv
-
-        def group_body(carry, pls):
-            x, lb, rz = carry
-            kvs = []
-            for i in range(grp):
-                pl = jax.tree_util.tree_map(lambda t: t[i], pls)
-                sw = win_sched[i] or None  # schedule is periodic per group
-                x, lb, rz, kv = one_layer(x, lb, rz, pl, sw)
-                kvs.append(kv)
-            ys = ()
-            if collect:
-                ys = (jnp.stack([k for k, _ in kvs]),
-                      jnp.stack([v for _, v in kvs]))
-            return (x, lb, rz), ys
-
-        head = jax.tree_util.tree_map(
-            lambda t: t[:nsb * grp].reshape(nsb, grp, *t.shape[1:]),
-            p["layers"])
-        lb0, rz0 = ZERO_AUX()
-        (x, lb, rz), ys = jax.lax.scan(jax.checkpoint(group_body),
-                                       (x, lb0, rz0), head)
-        tail_kvs = []
-        for j in range(tail):  # remainder layers (26 = 4*6 + 2 for gemma3)
-            li = nsb * grp + j
-            pl = jax.tree_util.tree_map(lambda t: t[li], p["layers"])
-            x, lb, rz, kv = one_layer(x, lb, rz, pl, win_sched[li] or None)
-            tail_kvs.append(kv)
-        L = cfg.n_layers
-        collected = None
-        if collect:
-            k_all = ys[0].reshape(nsb * grp, *ys[0].shape[2:])
-            v_all = ys[1].reshape(nsb * grp, *ys[1].shape[2:])
-            if tail_kvs:
-                k_all = jnp.concatenate(
-                    [k_all, jnp.stack([k for k, _ in tail_kvs])])
-                v_all = jnp.concatenate(
-                    [v_all, jnp.stack([v for _, v in tail_kvs])])
-            collected = ((k_all, v_all), ())
-        return x, (lb / L, rz / L), collected
-
-    def _sp(x):
-        """Megatron-SP (§Perf): keep the residual stream sequence-sharded
-        over 'model' between blocks; XLA then emits reduce-scatter before
-        the (sharded) norm/residual and all-gather after — same ring wire
-        bytes as the all-reduce but norms/adds touch 1/tp of the bytes."""
-        if not flags.seq_sharded_residual:
-            return x
-        from repro.dist.sharding import constrain
-        return constrain(x, ("batch", "seq_model", None))
-
     def body(carry, xs):
         x, lb, rz = carry
         pl, window = xs
-        x = _sp(x)
         h_in = apply_norm(cfg.norm, x, pl["norm1"])
         if cfg.mla is not None:
             h, kv = attn.mla_forward(pl["attn"], h_in, cos, sin, cfg=cfg)
@@ -529,7 +444,7 @@ def _fill_kv(cache_kv, new):
 def decode_forward(cfg: ModelConfig, params, cache, tokens, pos):
     """One decode step.  tokens: (B, 1); pos: scalar position (traced ok).
     Returns (logits (B, 1, V), new cache)."""
-    x = _embed_tokens_decode(cfg, params, tokens, pos)
+    x = _embed_tokens(cfg, params, tokens, pos)
     x, cache = _decode_layers(cfg, params, cache, x, pos)
     return _logits(cfg, params, x), cache
 
@@ -552,13 +467,20 @@ def _decode_layers(cfg: ModelConfig, params, cache, x, pos):
             body, x, (params["layers"], cache["conv"], cache["ssm"]))
         return x, dict(cache, conv=conv, ssm=st)
 
+    # Each layer reads its slice of the closed-over K/V cache and emits
+    # only the new position's entries; one write after the scan puts all
+    # of them into the cache.  Scanning the cache as xs would return it as
+    # a stacked output, written whole and copied back every step.  A
+    # recurrent state (conv, ssm) is replaced whole each step, so it is
+    # scanned.
     if cfg.family == "hybrid":
         k = cfg.hybrid_attn_every
         nsb = cfg.n_layers // k
         shared = params["shared_attn"]
+        full = (cache["k"], cache["v"])
 
         def superblock(x, xs):
-            pls, conv, st, ck, cv = xs
+            pls, conv, st, i = xs
 
             def mamba_body(x, ys):
                 pl, conv_i, st_i = ys
@@ -567,62 +489,32 @@ def _decode_layers(cfg: ModelConfig, params, cache, x, pos):
                     {"conv": conv_i, "ssm": st_i}, cfg)
                 return x + h, (new["conv"], new["ssm"])
             x, (conv, st) = jax.lax.scan(mamba_body, x, (pls, conv, st))
-            h, ck, cv = attn.gqa_decode(
+            mine = [jax.lax.dynamic_index_in_dim(c, i, keepdims=False)
+                    for c in full]
+            h, *new = attn.gqa_decode_step(
                 shared["attn"], apply_norm(cfg.norm, x, shared["norm1"]),
-                ck, cv, cos, sin, cfg=cfg, pos=pos)
+                *mine, cos, sin, cfg=cfg, pos=pos)
             x = x + h
             h = mlp_apply(shared["mlp"],
                           apply_norm(cfg.norm, x, shared["norm2"]),
                           cfg.activation)
-            return x + h, (conv, st, ck, cv)
+            return x + h, (conv, st, new)
 
         pls = jax.tree_util.tree_map(
             lambda t: t.reshape(nsb, k, *t.shape[1:]), params["layers"])
         conv = cache["conv"].reshape(nsb, k, *cache["conv"].shape[1:])
         st = cache["ssm"].reshape(nsb, k, *cache["ssm"].shape[1:])
-        x, (conv, st, ck, cv) = jax.lax.scan(
-            superblock, x, (pls, conv, st, cache["k"], cache["v"]))
-        cache = dict(cache, conv=conv.reshape(cache["conv"].shape),
-                     ssm=st.reshape(cache["ssm"].shape), k=ck, v=cv)
-        return x, cache
+        x, (conv, st, new) = jax.lax.scan(
+            superblock, x, (pls, conv, st, jnp.arange(nsb)))
+        return x, dict(cache, conv=conv.reshape(cache["conv"].shape),
+                       ssm=st.reshape(cache["ssm"].shape),
+                       **_write_at(("k", "v"), full, new, pos))
 
     windows = _window_schedule(cfg)
-
-    # §Perf: unrolled decode for local:global archs — local layers read an
-    # O(window) cache SLICE instead of sweeping the full S-long cache
-    from repro.dist import context as _dctx
-    if (_dctx.perf_flags().windowed_attention and cfg.mla is None
-            and cfg.family != "encdec" and cfg.local_global_ratio > 0
-            and cfg.window > 0):
-        grp = cfg.local_global_ratio + 1
-        win_sched = [0 if (i + 1) % grp == 0 else cfg.window
-                     for i in range(cfg.n_layers)]
-        cks, cvs = [], []
-        for li in range(cfg.n_layers):
-            pl = jax.tree_util.tree_map(lambda t: t[li], params["layers"])
-            h, ck, cv = attn.gqa_decode(
-                pl["attn"], apply_norm(cfg.norm, x, pl["norm1"]),
-                cache["k"][li], cache["v"][li], cos, sin, cfg=cfg, pos=pos,
-                static_window=win_sched[li] or None)
-            x = x + h
-            h_in = apply_norm(cfg.norm, x, pl["norm2"])
-            if "moe" in pl:
-                h, _ = moe_mod.moe_apply(pl["moe"], h_in, cfg)
-            else:
-                h = mlp_apply(pl["mlp"], h_in, cfg.activation)
-            x = x + h
-            cks.append(ck)
-            cvs.append(cv)
-        return x, dict(cache, k=jnp.stack(cks), v=jnp.stack(cvs))
-
     is_encdec, mla = cfg.family == "encdec", cfg.mla is not None
     names = ("ckv", "krope") if mla else ("k", "v")
     full = tuple(cache[n] for n in names)
 
-    # Each layer reads its slice of the closed-over cache and emits only
-    # the new position's entries; one write after the scan puts all L of
-    # them into the cache.  Scanning the cache as xs would return it as a
-    # stacked output, written whole and copied back every step.
     def body(x, xs):
         if is_encdec:
             pl, li, window, xk, xv = xs
@@ -644,10 +536,9 @@ def _decode_layers(cfg: ModelConfig, params, cache, x, pos):
                                            window=window)
         x = x + h
         if is_encdec:
-            h, _, _ = attn.gqa_decode(
-                pl["xattn"], apply_norm(cfg.norm, x, pl["norm_x"]),
-                None, None, None, None, cfg=cfg, pos=pos, xa_kv=(xk, xv))
-            x = x + h
+            x = x + attn.cross_decode(
+                pl["xattn"], apply_norm(cfg.norm, x, pl["norm_x"]), xk, xv,
+                cfg=cfg)
         h_in = apply_norm(cfg.norm, x, pl["norm2"])
         if "moe" in pl:
             h, _ = moe_mod.moe_apply(pl["moe"], h_in, cfg)
@@ -663,18 +554,13 @@ def _decode_layers(cfg: ModelConfig, params, cache, x, pos):
             xs += (cache["xk"], cache["xv"])
         x, new = jax.lax.scan(body, x, xs)
         news.append(new)
-    with jax.named_scope("kv_cache"):
-        written = {name: jax.lax.dynamic_update_slice(
-                       c, new, (0,) * (c.ndim - 2) + (pos, 0))
-                   for name, c, new in zip(names, full, _concat_layers(news))}
-    return x, dict(cache, **written)
+    return x, dict(cache, **_write_at(names, full, _concat_layers(news), pos))
 
 
-@jax.named_scope("embed")
-def _embed_tokens_decode(cfg: ModelConfig, p, tokens, pos):
-    x = p["embed"][tokens]
-    if cfg.family == "encdec":
-        x = x + jax.lax.dynamic_slice_in_dim(p["pos"], pos, 1, 0)[None]
-    if cfg.family in ("dense", "vlm", "moe") and cfg.name.startswith("gemma"):
-        x = x * (cfg.d_model ** 0.5)
-    return x.astype(jnp.bfloat16)
+@jax.named_scope("kv_cache")
+def _write_at(names, full, news, pos):
+    """{name: the cache ``full[i]`` with every layer's new entry
+    ``news[i]`` written at ``pos`` on its axis -2}."""
+    return {name: jax.lax.dynamic_update_slice(
+                c, new, (0,) * (c.ndim - 2) + (pos, 0))
+            for name, c, new in zip(names, full, news)}

@@ -3,8 +3,7 @@ query heads of a group together against their one K/V head, against a plain
 float64 reference that repeats every K/V head for its query heads, at
 granite's (G 2, hd 64) and Phi-3's (G 1, hd 96) head shapes: the new key
 merged as the key at ``pos`` over a cache that does not hold it yet, a
-window that binds, the static-window slice, and the cache that already
-holds ``pos``.  Also: the products are asked for exact, and GQA decode
+window that binds, and the cache that already holds ``pos``.  Also: the products are asked for exact, and GQA decode
 under a device mesh gives the one-device tokens."""
 import subprocess
 import sys
@@ -32,13 +31,13 @@ def _inputs(G, hd, seed):
     return q, kc, vc, kn, vn
 
 
-def _plain(q, kc, vc, pos, window=0, k_pos=None):
+def _plain(q, kc, vc, pos, window=0):
     """Softmax attention of each query head over its group's K/V head
-    repeated, float64, keys at ``k_pos`` <= pos and inside the window."""
+    repeated, float64, keys at positions <= pos and inside the window."""
     q, kc, vc = (np.asarray(t, np.float64) for t in (q, kc, vc))
     G = q.shape[1] // kc.shape[1]
     kc, vc = np.repeat(kc, G, axis=1), np.repeat(vc, G, axis=1)
-    t = np.arange(kc.shape[2]) if k_pos is None else np.asarray(k_pos)
+    t = np.arange(kc.shape[2])
     valid = (t <= pos) & ((window <= 0) | (pos - t < window))
     s = np.einsum("bhqd,bhsd->bhqs", q, kc) / np.sqrt(q.shape[-1])
     s = np.where(valid, s, -np.inf)
@@ -76,19 +75,13 @@ def test_new_key_is_attended_as_the_key_at_pos(G, hd, pos, window):
 
 
 @pytest.mark.parametrize("G,hd", [(2, 64), (1, 96)], ids=["granite", "phi3"])
-def test_a_cache_that_holds_pos_and_a_static_window(G, hd):
-    """Without ``new`` the cache holds ``pos`` already (cross-attention,
-    the hybrid and static-window branches); ``k_pos`` places a window's
-    slice of the cache."""
+def test_a_cache_that_already_holds_pos(G, hd):
+    """Without ``new`` the cache holds ``pos`` already, as the encoder's
+    K/V do for cross-attention: keys up to ``pos`` are read, the rest
+    masked."""
     q, kc, vc, _, _ = _inputs(G, hd, seed=7)
     out = decode_attention(q, kc, vc, pos=jnp.int32(200))
     np.testing.assert_allclose(out, _plain(q, kc, vc, 200), **TOL)
-    k_pos = 120 + jnp.arange(64)
-    out = decode_attention(q, kc[:, :, 120:184], vc[:, :, 120:184],
-                           pos=jnp.int32(183), k_pos=k_pos)
-    np.testing.assert_allclose(
-        out, _plain(q, kc[:, :, 120:184], vc[:, :, 120:184], 183,
-                    k_pos=k_pos), **TOL)
 
 
 def test_products_are_asked_for_exact():
